@@ -59,8 +59,8 @@ from typing import Dict, List, Optional, Tuple
 
 from repro.core.epilogue import Epilogue
 from repro.core.geometry import (
-    BlockGeometry, Policy, TPU_V5E, TpuProfile, cdiv, round_up,
-    solve_block_geometry,
+    BlockGeometry, Policy, TPU_V5E, TpuProfile, cdiv, device_profile,
+    round_up, solve_block_geometry,
 )
 from repro.core.perfmodel import tpu_gemm_time
 from repro.core.tile_state import SEW
@@ -68,6 +68,7 @@ from repro.core.tile_state import SEW
 __all__ = [
     "GemmSignature", "ExecutionPlan", "PlanCache", "CacheStats",
     "enumerate_candidates", "execute_plan", "get_plan", "plan_with_geometry",
+    "unsupported",
     "plan_cache",
     "reset_cache", "configure", "cache_stats", "save_plans", "load_plans",
     "benchmark_shape", "benchmark_format", "DEFAULT_N_CORES",
@@ -270,6 +271,19 @@ def enumerate_candidates(sig: GemmSignature,
     return cands
 
 
+def unsupported(plan: ExecutionPlan) -> Optional[str]:
+    """Why ``plan``'s route cannot execute its signature, or None.
+
+    Decided from the signature before anything launches, so a plan
+    search never has to tell a capability mismatch from a kernel that
+    fails to compile.
+    """
+    epi = plan.signature.epilogue
+    if plan.route == "mte" and epi.has_bias and epi.bias_axis != "row":
+        return "the MTE kernel fuses row bias only"
+    return None
+
+
 def score_geometry(sig: GemmSignature, geom: BlockGeometry,
                    profile: TpuProfile = TPU_V5E,
                    n_cores: int = DEFAULT_N_CORES) -> float:
@@ -434,15 +448,24 @@ class PlanCache:
     """In-process LRU of GemmSignature → ExecutionPlan with JSON warm-start."""
 
     def __init__(self, maxsize: int = 4096,
-                 profile: TpuProfile = TPU_V5E,
+                 profile: Optional[TpuProfile] = None,
                  n_cores: int = DEFAULT_N_CORES,
                  measure_top: int = 4):
         self.maxsize = maxsize
-        self.profile = profile
+        self._profile = profile
         self.n_cores = n_cores
         self.measure_top = measure_top
         self._plans: "OrderedDict[GemmSignature, ExecutionPlan]" = OrderedDict()
         self.stats = CacheStats()
+
+    @property
+    def profile(self) -> TpuProfile:
+        """The hardware profile plans are solved for: the one given, or
+        else the device's (``geometry.device_profile``), looked up on
+        first use so that importing this module touches no device."""
+        if self._profile is None:
+            self._profile = device_profile()
+        return self._profile
 
     def __len__(self) -> int:
         return len(self._plans)
@@ -514,18 +537,15 @@ class PlanCache:
                                             predicted_s=best_s))
         timed: List[Tuple[float, ExecutionPlan]] = []
         for cand in candidates:
-            try:
-                t = measure_plan(cand, interpret=interpret)
-            except (ValueError, NotImplementedError):
-                # Capability mismatch (e.g. the MTE kernel fuses row
-                # bias only): this candidate cannot execute for this
-                # signature, so it cannot win.  Anything else (lowering
-                # bugs, shape errors in a kernel) propagates — silent
-                # fallback would hide real kernel regressions.
+            if unsupported(cand) is not None:
+                # This route cannot execute the signature, so it cannot
+                # win.  Whatever a launch raises (a compile or lowering
+                # error, a misaligned block) propagates: a silent
+                # fallback to another route would hide a broken kernel.
                 self.stats.measure_failed += 1
                 continue
             self.stats.measured += 1
-            timed.append((t, cand))
+            timed.append((measure_plan(cand, interpret=interpret), cand))
         if not timed:
             return plan  # nothing executable to measure: analytic grant
         t_best, p_best = min(timed, key=lambda x: x[0])
@@ -677,7 +697,7 @@ def cache_generation() -> int:
 
 
 def reset_cache(maxsize: int = 4096, n_cores: int = DEFAULT_N_CORES,
-                profile: TpuProfile = TPU_V5E) -> PlanCache:
+                profile: Optional[TpuProfile] = None) -> PlanCache:
     """Replace the process-global cache (tests / reconfiguration)."""
     global _GLOBAL, _GENERATION
     _GLOBAL = PlanCache(maxsize=maxsize, profile=profile, n_cores=n_cores)

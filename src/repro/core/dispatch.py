@@ -6,8 +6,9 @@ calls into.  It plays the role the MTE ISA plays in the paper: callers
 state *what* they want (operand shapes, dtypes, epilogue) and the
 dispatch layer *grants* an execution plan and routes to a backend:
 
-- ``backend="pallas"``      — kernel-backed execution (interpret=True on
-                              CPU, compiled Mosaic on a real TPU).
+- ``backend="pallas"``      — kernel-backed execution (compiled Mosaic on
+                              a TPU, the Pallas interpreter elsewhere;
+                              ``interpret=`` overrides).
 - ``backend="xla"``         — plain jnp.dot + fused-by-XLA epilogue.  Used
                               inside pjit'd training/serving graphs and for
                               the multi-pod dry-run (Mosaic cannot lower on
@@ -116,7 +117,7 @@ def mte_gemm(a, b, c=None, bias=None, *,
              backend: str = _DEFAULT_BACKEND,
              out_dtype=None,
              format_policy=None,
-             interpret: bool = True):
+             interpret: Optional[bool] = None):
     """Compute ``epilogue(a @ b [, c, bias])`` with a plan-cached schedule.
 
     a: (M, K); b: (K, N); optional c: (M, N) when ``epilogue.beta != 0``;

@@ -46,6 +46,8 @@ __all__ = [
     "BlockGeometry",
     "PROFILES",
     "TPU_V5E",
+    "TPU_PROFILES",
+    "device_profile",
     "max_tile_dims",
     "solve_unroll",
     "solve_block_geometry",
@@ -345,6 +347,29 @@ class TpuProfile:
 
 
 TPU_V5E = TpuProfile()
+
+# TPU profiles by ``jax.Device.device_kind``.
+TPU_PROFILES = {"TPU v5 lite": TPU_V5E}
+
+
+def device_profile() -> TpuProfile:
+    """The profile of the device JAX computes on.
+
+    A TPU is looked up by its ``device_kind``: a kind missing from
+    ``TPU_PROFILES`` raises instead of being planned as another chip.
+    Off the TPU (the CPU, the Pallas interpreter) the described target
+    stays v5e.
+    """
+    import jax
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
+        return TPU_V5E
+    try:
+        return TPU_PROFILES[dev.device_kind]
+    except KeyError:
+        raise ValueError(f"no TpuProfile for device kind "
+                         f"{dev.device_kind!r}; add one to "
+                         f"TPU_PROFILES") from None
 
 
 @dataclasses.dataclass(frozen=True)

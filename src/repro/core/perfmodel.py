@@ -218,6 +218,7 @@ class TpuGemmTiming:
     useful_flops: int
     padded_flops: int
     hbm_bytes: int
+    profile: TpuProfile = TPU_V5E
 
     @property
     def seconds(self) -> float:
@@ -225,8 +226,7 @@ class TpuGemmTiming:
 
     @property
     def efficiency(self) -> float:
-        profile = TPU_V5E
-        peak = profile.peak_flops(self.geom.sew_i)
+        peak = self.profile.peak_flops(self.geom.sew_i)
         return self.useful_flops / (self.seconds * peak)
 
     @property
@@ -281,7 +281,8 @@ def tpu_gemm_time(geom: BlockGeometry, m: int, n: int, k: int,
 
     return TpuGemmTiming(geom=geom, m=m, n=n, k=k, compute_s=compute_s,
                          memory_s=memory_s, useful_flops=useful_flops,
-                         padded_flops=padded_flops, hbm_bytes=hbm)
+                         padded_flops=padded_flops, hbm_bytes=hbm,
+                         profile=profile)
 
 
 def analytic_seconds(m: int, n: int, k: int, *, fmt: str = "fp32",

@@ -37,8 +37,7 @@ def constrain(x, *dims):
 
     ``dims`` entries: None, an axis name, or a tuple of axis names.
     """
-    from repro.distributed.compat import get_abstract_mesh
-    am = get_abstract_mesh()
+    am = jax.sharding.get_abstract_mesh()
     if am is None or getattr(am, "empty", True):
         return x
     names = set(am.axis_names)

@@ -110,7 +110,7 @@ def flash_attention_pallas(q, k, v, *, causal: bool = True,
                            softcap: Optional[float] = None,
                            scale: Optional[float] = None,
                            block_q: int = 256, block_kv: int = 256,
-                           interpret: bool = True):
+                           interpret: bool):
     """Flash attention; q (B,H,Sq,D), k/v (B,Hkv,Skv,D), H % Hkv == 0."""
     b, h, sq, d = q.shape
     _, hkv, skv, _ = k.shape

@@ -88,7 +88,7 @@ def flash_decode_pallas(q, k, v, kv_positions, q_pos, *,
                         window: Optional[int] = None,
                         softcap: Optional[float] = None,
                         scale: Optional[float] = None,
-                        block_kv: int = 512, interpret: bool = True):
+                        block_kv: int = 512, interpret: bool):
     """One-token attention.  q (B,H,D); k/v (B,Hkv,S,D);
     kv_positions (B,S); q_pos (B,).  Returns (B, H, D) in q.dtype."""
     b, h, d = q.shape
@@ -210,7 +210,7 @@ def flash_decode_paged_pallas(q, k_pages, v_pages, page_table, seq_lens,
                               window: Optional[int] = None,
                               softcap: Optional[float] = None,
                               scale: Optional[float] = None,
-                              interpret: bool = True):
+                              interpret: bool):
     """One-token attention over a **paged** KV cache.
 
     q (B, H, D); k_pages/v_pages (P, page, Hkv, D) — fixed-size pages

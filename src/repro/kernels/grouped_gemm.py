@@ -60,7 +60,7 @@ def _kernel(x_ref, w_ref, o_ref, acc_ref, *, nk: int, k: int, bk: int,
 def grouped_gemm_pallas(x, w, *, geom: BlockGeometry,
                         epilogue: Epilogue = Epilogue(),
                         out_dtype=jnp.float32, acc_dtype=None,
-                        interpret: bool = True):
+                        interpret: bool):
     """Per-expert GEMM with the accumulator at the format policy's
     ``SEW_o`` (f32 by default, int32 for int8 operands, bf16 for the
     narrow-accumulator fast path)."""

@@ -114,7 +114,7 @@ def _clip_block(block: int, dim: int) -> int:
 def mte_gemm_pallas(a, b, c=None, bias=None, *, geom: BlockGeometry,
                     epilogue: Epilogue = Epilogue(),
                     out_dtype=jnp.float32, acc_dtype=None,
-                    interpret: bool = True):
+                    interpret: bool):
     """``epilogue(a @ b [, c, bias])`` with an MTE-solved block schedule.
 
     a: (M, K); b: (K, N), or (N, K) when ``geom.transposed_b`` (Formula 3

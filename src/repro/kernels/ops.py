@@ -9,9 +9,9 @@ shapes whose (M, N) grid underfills the machine, or the rigid baseline.
 ``format_policy`` (see :mod:`repro.core.formats`) selects the operand /
 accumulator element widths: operands are cast (bf16 / bf16acc) or
 symmetric-per-channel quantized (int8 → integer dot → dequantize
-epilogue) here, once, instead of at every call site.  ``interpret``
-defaults to True off-TPU so the same entry points run under CPU tests
-and compile to Mosaic on real hardware.
+epilogue) here, once, instead of at every call site.  ``interpret=None``
+compiles to Mosaic on a TPU and runs the Pallas interpreter elsewhere, so
+the same entry points serve CPU tests and the chip.
 """
 from __future__ import annotations
 

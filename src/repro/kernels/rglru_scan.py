@@ -42,7 +42,7 @@ def _kernel(a_ref, b_ref, o_ref, h_ref, *, bt: int):
 
 
 @functools.partial(jax.jit, static_argnames=("block_t", "interpret"))
-def rglru_scan_pallas(a, b, *, block_t: int = 64, interpret: bool = True):
+def rglru_scan_pallas(a, b, *, block_t: int = 64, interpret: bool):
     """h_t = a_t·h_{t-1} + b_t along axis 1.  a, b: (B, S, W) f32."""
     bsz, s, w = a.shape
     bt = min(block_t, s)

@@ -42,7 +42,7 @@ def _epilogue_kernel(acc_ref, c_ref, bias_ref, o_ref, *, epilogue: Epilogue):
     jax.jit, static_argnames=("epilogue", "out_dtype", "interpret"))
 def epilogue_pass_pallas(acc, c=None, bias=None, *,
                          epilogue: Epilogue = Epilogue(),
-                         out_dtype=jnp.float32, interpret: bool = True):
+                         out_dtype=jnp.float32, interpret: bool):
     """Standalone element-wise epilogue pass (the AVX-512-through-memory leg)."""
     m, n = acc.shape
     bm = min(256, max(8, cdiv(m, 8) * 8))
@@ -79,7 +79,7 @@ def epilogue_pass_pallas(acc, c=None, bias=None, *,
 
 def rigid_gemm_pallas(a, b, c=None, bias=None, *,
                       epilogue: Epilogue = Epilogue(),
-                      out_dtype=jnp.float32, interpret: bool = True):
+                      out_dtype=jnp.float32, interpret: bool):
     """AMX-semantics GEMM: fixed 128³ blocks + epilogue via HBM round trip."""
     sew_i = SEW.from_dtype(a.dtype)
     sew_o = SEW.from_dtype(out_dtype)

@@ -28,7 +28,7 @@ __all__ = ["mte_gemm_splitk_pallas"]
 
 
 def _kernel(a_ref, b_ref, o_ref, acc_ref, *, nk: int, k: int, bk: int,
-            k_per_split: int):
+            k_per_split: int, masked: bool):
     si = pl.program_id(0)
     ki = pl.program_id(3)
 
@@ -38,13 +38,18 @@ def _kernel(a_ref, b_ref, o_ref, acc_ref, *, nk: int, k: int, bk: int,
 
     a = a_ref[...]
     b = b_ref[...]
-    # global K offset of this block; mask anything past the true K
-    k_start = si * k_per_split + ki * bk
-    limit = jnp.clip(k - k_start, 0, bk)
-    ka = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1) < limit
-    a = jnp.where(ka, a, jnp.zeros_like(a))
-    kb = jax.lax.broadcasted_iota(jnp.int32, b.shape, 0) < limit
-    b = jnp.where(kb, b, jnp.zeros_like(b))
+    if masked:
+        # global K offset of this block; mask anything past the true K
+        k_start = si * k_per_split + ki * bk
+        limit = jnp.clip(k - k_start, 0, bk)
+        ka = jax.lax.broadcasted_iota(jnp.int32, a.shape, 1) < limit
+        # Mosaic cannot select on a packed (bf16/int8) block with fewer
+        # rows than its sublane tile (decode M=4), so mask at 32 bits.
+        wide = (jnp.int32 if jnp.issubdtype(a.dtype, jnp.integer)
+                else jnp.float32)
+        a = jnp.where(ka, a.astype(wide), 0).astype(a.dtype)
+        kb = jax.lax.broadcasted_iota(jnp.int32, b.shape, 0) < limit
+        b = jnp.where(kb, b, jnp.zeros_like(b))
     acc_ref[...] += jax.lax.dot_general(
         a, b, (((1,), (0,)), ((), ())),
         preferred_element_type=acc_ref.dtype)
@@ -61,7 +66,7 @@ def mte_gemm_splitk_pallas(a, b, c=None, bias=None, *, geom: BlockGeometry,
                            n_split: int = 4,
                            epilogue: Epilogue = Epilogue(),
                            out_dtype=jnp.float32, acc_dtype=None,
-                           interpret: bool = True):
+                           interpret: bool):
     """``epilogue(a @ b [, c, bias])`` with the K loop split over
     ``n_split`` grid slices (partials in the format's accumulator dtype —
     f32 by default, int32 for quantized int8 operands — + final fused
@@ -79,7 +84,7 @@ def mte_gemm_splitk_pallas(a, b, c=None, bias=None, *, geom: BlockGeometry,
     if epilogue.has_bias and bias is None:
         raise ValueError("epilogue.has_bias requires bias operand")
 
-    bm = min(geom.bm, max(8, cdiv(m, 8) * 8))
+    bm = m if m <= geom.bm else geom.bm
     bn = min(geom.bn, max(128, cdiv(n, 128) * 128))
     bk = min(geom.bk, max(8, cdiv(k, 8) * 8))
     k_per_split = cdiv(cdiv(k, n_split), bk) * bk
@@ -87,7 +92,8 @@ def mte_gemm_splitk_pallas(a, b, c=None, bias=None, *, geom: BlockGeometry,
     gm, gn = cdiv(m, bm), cdiv(n, bn)
 
     kernel = functools.partial(_kernel, nk=gk, k=k, bk=bk,
-                               k_per_split=k_per_split)
+                               k_per_split=k_per_split,
+                               masked=n_split * k_per_split != k)
     partials = pl.pallas_call(
         kernel,
         grid=(n_split, gm, gn, gk),

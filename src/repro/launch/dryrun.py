@@ -37,7 +37,6 @@ import jax.numpy as jnp
 
 from repro.configs import ARCH_NAMES, SHAPES, get_config, input_specs
 from repro.configs.base import ArchConfig, ShapeSpec
-from repro.distributed import compat
 from repro.distributed import sharding as sh
 from repro.launch.mesh import make_production_mesh
 from repro.models import model as model_lib
@@ -149,7 +148,7 @@ def build_cell(cfg: ArchConfig, shape: ShapeSpec, mesh,
 def _compile_and_measure(cfg, shape, mesh,
                          microbatches: int = 1) -> Dict[str, Any]:
     t0 = time.time()
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         jitted, args = build_cell(cfg, shape, mesh, microbatches)
         lowered = jitted.lower(*args)
         t1 = time.time()

@@ -30,6 +30,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as model_lib
 from repro.serving.engine import Request, ServingEngine
 from repro.serving.resilience import FaultInjector, Shed
@@ -155,6 +156,7 @@ def main():
         args.slo = bool(args.status_json or args.prom or args.watch)
     if args.profile is None:
         args.profile = bool(args.status_json)
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:
@@ -185,8 +187,10 @@ def main():
         from repro.telemetry.slo import SloMonitor
         slo_monitor = SloMonitor()
 
-    params = model_lib.init_params(jax.random.PRNGKey(0), cfg)
-    engine = ServingEngine(params, cfg, slots=args.slots,
+    # Passed straight through: the engine holds its own serving copy of
+    # the weights, and nothing here keeps the initialised ones alive.
+    engine = ServingEngine(model_lib.init_params(jax.random.PRNGKey(0), cfg),
+                           cfg, slots=args.slots,
                            cache_len=args.cache_len,
                            prefill_len=args.prefill_len,
                            page_size=args.page_size,

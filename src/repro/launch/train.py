@@ -26,9 +26,9 @@ import numpy as np
 from repro.checkpoint.manager import CheckpointManager
 from repro.configs import get_config
 from repro.data.pipeline import DataConfig, SyntheticDataset
-from repro.distributed import compat
 from repro.distributed import sharding as sh
 from repro.distributed.fault import StepWatchdog, supervise
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_elastic_mesh
 from repro.models import model as model_lib
 from repro.optim.optimizer import AdamWConfig, init_opt_state
@@ -49,18 +49,20 @@ def train_loop(cfg, *, steps: int, batch: int, seq: int, lr: float = 3e-4,
                                        global_batch=batch, seed=seed))
     ckpt = CheckpointManager(ckpt_dir) if ckpt_dir else None
 
-    with compat.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         params_shape = jax.eval_shape(
             lambda: model_lib.init_params(jax.random.PRNGKey(seed), cfg))
         p_spec = sh.param_specs(cfg, params_shape, mesh)
         p_shard = sh.named_shardings(mesh, p_spec)
+        # Adam's moments are laid out like the parameters.  The compiler
+        # cannot infer that for zeros, so it is stated.
+        o_shard = sh.named_shardings(
+            mesh, {"m": p_spec, "v": p_spec,
+                   "step": jax.sharding.PartitionSpec()})
 
         start_step = 0
         if ckpt and ckpt.latest_step() is not None:
             opt_shape = jax.eval_shape(init_opt_state, params_shape)
-            o_shard = sh.named_shardings(
-                mesh, {"m": p_spec, "v": p_spec,
-                       "step": jax.sharding.PartitionSpec()})
             params, opt_state, manifest = ckpt.restore(
                 None, (params_shape, opt_shape), (p_shard, o_shard))
             start_step = int(manifest["step"])
@@ -75,7 +77,8 @@ def train_loop(cfg, *, steps: int, batch: int, seq: int, lr: float = 3e-4,
                 lambda key: model_lib.init_params(key, cfg),
                 out_shardings=p_shard)
             params = init_fn(jax.random.PRNGKey(seed))
-            opt_state = jax.jit(init_opt_state)(params)
+            opt_state = jax.jit(init_opt_state,
+                                out_shardings=o_shard)(params)
 
         step_fn = jax.jit(make_train_step(cfg, opt_cfg, microbatches),
                           donate_argnums=(0, 1))
@@ -139,6 +142,7 @@ def main():
                          "repro.graph programs (debugging escape hatch; "
                          "compiled is the default)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

@@ -33,7 +33,6 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
-from repro.distributed import compat
 from repro.models import attention as attn_mod
 from repro.models import moe as moe_mod
 from repro.models import rglru as rglru_mod
@@ -285,7 +284,7 @@ def _moe_dispatch(h, ffn_params, cfg: ArchConfig):
     to the GSPMD scatter path (also the single-device smoke-test path).
     """
     if cfg.moe_impl == "a2a":
-        am = compat.get_abstract_mesh()
+        am = jax.sharding.get_abstract_mesh()
         if (am is not None and not getattr(am, "empty", True)
                 and "model" in am.axis_names
                 and h.shape[1] % am.shape["model"] == 0):

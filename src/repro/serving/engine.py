@@ -130,7 +130,38 @@ from repro.serving.scheduler import ContinuousBatchingScheduler
 from repro.telemetry import tracing
 from repro.telemetry.registry import registry as metrics_registry
 
-__all__ = ["Request", "ServingEngine"]
+__all__ = ["Request", "ServingEngine", "serving_params"]
+
+
+def serving_params(params, cfg: ArchConfig):
+    """The parameters as the engine holds them: GEMM weights (``w``
+    leaves) and the embedding table / LM head stored at the format
+    policy's operand width.
+
+    The cast applies only when it is exact for every reader: under an
+    unquantized policy whose operand dtype is the compute dtype, each
+    consumer (the GEMM's operand cast, ``embed``'s cast to the compute
+    dtype, ``unembed``'s cast to the operand dtype) rounds these leaves
+    to that same dtype first.  Holding them narrow then changes no
+    result, and gemma-2b's bf16 weights take 5 GB of the chip's 16 GB
+    instead of 10 GB plus a converted copy inside every program.
+    Norms, biases and every other leaf keep their dtype.
+    """
+    from repro.models.layers import model_format
+    fmt = model_format(cfg)
+    narrow = jnp.dtype(fmt.operand_jnp)
+    if fmt.quantized or narrow != jnp.dtype(cfg.compute_dtype):
+        return params
+
+    def hold(path, x):
+        name = getattr(path[-1], "key", None)
+        if (name in ("w", "table", "head")
+                and jnp.issubdtype(x.dtype, jnp.floating)
+                and x.dtype != narrow):
+            return x.astype(narrow)
+        return x
+
+    return jax.tree_util.tree_map_with_path(hold, params)
 
 
 def _stack_decode_qkv(params):
@@ -227,6 +258,7 @@ class ServingEngine:
         cfg = dataclasses.replace(cfg, cache_quant=False,
                                   kv_cache_format=kv_format,
                                   decode_qkv_grouped=bool(grouped_qkv))
+        params = serving_params(params, cfg)
         if grouped_qkv:
             params = _stack_decode_qkv(params)
         self.params = params
@@ -865,9 +897,11 @@ class ServingEngine:
         # Row-valid mask: ring/recurrent cache rows of slots that are
         # not decoding this step keep their prior state inside the
         # decode program itself.  Always passed for stateful archs so
-        # the jit signature is stable.
+        # the jit signature is stable.  ``slot_pos`` is copied: the
+        # device array may alias the host buffer until the launch has
+        # read it, and the positions advance in place just below.
         batch = self._make_batch(
-            self._last_tok, pos=self.slot_pos, table=table,
+            self._last_tok, pos=self.slot_pos.copy(), table=table,
             row_valid=active if self._stateful_rows else None)
         # The decode span stays open until delivery: it covers the
         # device-resident window, so async traces show decode visibly
@@ -1063,14 +1097,10 @@ class ServingEngine:
                                  slot=slot)
         if self.fault is not None:
             self.fault.chunk_fault(self.step_idx, req.rid, c)
-        try:
-            logits, self.cache = self._chunk_fn(req.format_policy, c)(
-                self.params, batch, self.cache)
-        except RequestError:
-            raise
-        except Exception as e:  # real compute failure: contain to the request
-            raise RequestError(f"chunk compute failed (rid={req.rid}, "
-                               f"chunk={c}): {e}", rid=req.rid) from e
+        # A compile or lowering error here is a defect of the program,
+        # not of this request: it propagates instead of being contained.
+        logits, self.cache = self._chunk_fn(req.format_policy, c)(
+            self.params, batch, self.cache)
         # Publish the chunk's fully-written pages to the prefix cache —
         # only now: an eviction mid-prefill must never leave a
         # half-written page findable.

@@ -176,17 +176,18 @@ class DispatchProfiler:
         measured = 0
         for key in todo[:budget]:
             plan = self._plan_for(key)
-            try:
-                with gemm_account.suppress():
-                    self._measured[key] = autotune.measure_plan(
-                        plan, iters=self.iters, interpret=self.interpret)
-                measured += 1
-            except (ValueError, NotImplementedError) as e:
+            reason = autotune.unsupported(plan)
+            if reason is not None:
                 # Same contract as PlanCache._build: a capability
                 # mismatch means this signature cannot be replayed
                 # standalone — it stays in the dispatch counts, out of
-                # the measured aggregate.  Real kernel bugs propagate.
-                self._failed[key] = str(e)
+                # the measured aggregate.  Launch errors propagate.
+                self._failed[key] = reason
+                continue
+            with gemm_account.suppress():
+                self._measured[key] = autotune.measure_plan(
+                    plan, iters=self.iters, interpret=self.interpret)
+            measured += 1
         return measured
 
     # -- the calibration table -------------------------------------------------
@@ -299,16 +300,15 @@ class DispatchProfiler:
         for _, n_disp, sig in hot[:int(top_k)]:
             granted = cache._plans.get(sig)
             runner = cache.runner_up(sig)
-            if granted is None or runner is None:
+            if (granted is None or runner is None
+                    or autotune.unsupported(granted) is not None
+                    or autotune.unsupported(runner) is not None):
                 continue
-            try:
-                with gemm_account.suppress():
-                    t_granted = autotune.measure_plan(
-                        granted, iters=self.iters, interpret=self.interpret)
-                    t_runner = autotune.measure_plan(
-                        runner, iters=self.iters, interpret=self.interpret)
-            except (ValueError, NotImplementedError):
-                continue
+            with gemm_account.suppress():
+                t_granted = autotune.measure_plan(
+                    granted, iters=self.iters, interpret=self.interpret)
+                t_runner = autotune.measure_plan(
+                    runner, iters=self.iters, interpret=self.interpret)
             regret = (t_granted - t_runner) / max(t_runner, 1e-12)
             flagged = t_granted > t_runner * (1.0 + tol)
             entry: Dict[str, object] = {

@@ -192,3 +192,33 @@ def test_measured_refinement_picks_a_candidate():
     plan = autotune.get_plan(8, 256, 512, jnp.float32, measure=True)
     assert plan.source == "measured" and plan.measured_s is not None
     assert autotune.cache_stats().measured >= 2
+
+
+def test_measured_refinement_propagates_launch_errors(monkeypatch):
+    """A candidate that fails to launch (a kernel the compiler refuses)
+    must not silently lose the search to another route."""
+    def refused(plan, iters=3, interpret=None):
+        if plan.route != "xla":
+            raise ValueError("block shape not aligned to the tiling")
+        return 1e-3
+
+    monkeypatch.setattr(autotune, "measure_plan", refused)
+    with pytest.raises(ValueError, match="aligned"):
+        autotune.get_plan(8, 256, 512, jnp.float32, measure=True)
+
+
+def test_capability_mismatch_is_decided_before_launch(monkeypatch):
+    """The MTE kernel fuses row bias only: a column-bias signature skips
+    that route without launching it and counts it in measure_failed."""
+    launched = []
+
+    def timed(plan, iters=3, interpret=None):
+        launched.append(plan.route)
+        return 1e-3
+
+    monkeypatch.setattr(autotune, "measure_plan", timed)
+    epi = Epilogue(has_bias=True, bias_axis="col")
+    plan = autotune.get_plan(256, 256, 256, jnp.float32, epilogue=epi,
+                             measure=True)
+    assert "mte" not in launched and plan.route != "mte"
+    assert autotune.cache_stats().measure_failed >= 1

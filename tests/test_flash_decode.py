@@ -77,7 +77,8 @@ def _paged_layout(k, v, seq_lens, page):
 def test_flat_kernel_matches_reference_ragged():
     q, k, v, kvpos, qpos = _ragged_inputs()
     out = flash_decode_pallas(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
-                              jnp.asarray(kvpos), jnp.asarray(qpos))
+                              jnp.asarray(kvpos), jnp.asarray(qpos),
+                              interpret=True)
     ref = _np_reference(q, k, v, SEQ_LENS)
     np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-5, atol=2e-5)
 
@@ -90,7 +91,7 @@ def test_flat_kernel_bf16_storage():
     vb = jnp.asarray(v).astype(jnp.bfloat16)
     qb = jnp.asarray(q).astype(jnp.bfloat16)
     out = flash_decode_pallas(qb, kb, vb, jnp.asarray(kvpos),
-                              jnp.asarray(qpos))
+                              jnp.asarray(qpos), interpret=True)
     assert out.dtype == jnp.bfloat16
     ref = _np_reference(np.asarray(qb.astype(jnp.float32)),
                         np.asarray(kb.astype(jnp.float32)),
@@ -103,7 +104,7 @@ def test_flat_kernel_softcap_and_window():
     q, k, v, kvpos, qpos = _ragged_inputs(seed=1)
     out = flash_decode_pallas(jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
                               jnp.asarray(kvpos), jnp.asarray(qpos),
-                              window=8, softcap=30.0)
+                              window=8, softcap=30.0, interpret=True)
     ref = _np_reference(q, k, v, SEQ_LENS, window=8, softcap=30.0)
     np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-5, atol=2e-5)
 
@@ -115,11 +116,11 @@ def test_paged_kernel_matches_flat(page):
     q, k, v, kvpos, qpos = _ragged_inputs(seed=2)
     flat = flash_decode_pallas(jnp.asarray(q), jnp.asarray(k),
                                jnp.asarray(v), jnp.asarray(kvpos),
-                               jnp.asarray(qpos))
+                               jnp.asarray(qpos), interpret=True)
     k_pages, v_pages, table = _paged_layout(k, v, SEQ_LENS, page)
     out = flash_decode_paged_pallas(
         jnp.asarray(q), jnp.asarray(k_pages), jnp.asarray(v_pages),
-        jnp.asarray(table), jnp.asarray(SEQ_LENS))
+        jnp.asarray(table), jnp.asarray(SEQ_LENS), interpret=True)
     np.testing.assert_allclose(np.asarray(out), np.asarray(flat),
                                rtol=2e-5, atol=2e-5)
 
@@ -141,7 +142,7 @@ def test_paged_kernel_ignores_stale_page_contents():
         v_bad[last, n % page or page:] = 1e6
     out = flash_decode_paged_pallas(
         jnp.asarray(q), jnp.asarray(k_bad), jnp.asarray(v_bad),
-        jnp.asarray(table), jnp.asarray(SEQ_LENS))
+        jnp.asarray(table), jnp.asarray(SEQ_LENS), interpret=True)
     ref = _np_reference(q, k, v, SEQ_LENS)
     np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-5, atol=2e-5)
 
@@ -164,7 +165,7 @@ def test_paged_kernel_int8_scales_in_kernel():
     out = flash_decode_paged_pallas(
         jnp.asarray(q), jnp.asarray(kq), jnp.asarray(vq),
         jnp.asarray(table), jnp.asarray(SEQ_LENS),
-        jnp.asarray(ks), jnp.asarray(vs))
+        jnp.asarray(ks), jnp.asarray(vs), interpret=True)
     ref = _np_reference(q, k, v, SEQ_LENS)
     err = np.max(np.abs(np.asarray(out) - ref))
     span = np.max(np.abs(ref)) + 1e-6
@@ -177,6 +178,6 @@ def test_paged_kernel_window():
     k_pages, v_pages, table = _paged_layout(k, v, SEQ_LENS, page)
     out = flash_decode_paged_pallas(
         jnp.asarray(q), jnp.asarray(k_pages), jnp.asarray(v_pages),
-        jnp.asarray(table), jnp.asarray(SEQ_LENS), window=6)
+        jnp.asarray(table), jnp.asarray(SEQ_LENS), window=6, interpret=True)
     ref = _np_reference(q, k, v, SEQ_LENS, window=6)
     np.testing.assert_allclose(np.asarray(out), ref, rtol=2e-5, atol=2e-5)

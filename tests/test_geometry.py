@@ -5,6 +5,8 @@ try:
 except ImportError:  # hermetic env: run properties via the local shim
     from _hypothesis_fallback import given, settings, strategies as st
 
+import pytest
+
 from repro.core.geometry import (
     PROFILES, TPU_V5E, max_tile_dims, sifive_tile_dims, solve_block_geometry,
     solve_unroll,
@@ -94,3 +96,24 @@ def test_mte_adapts_small_dims_amx_does_not(m, n, k):
         assert g.bm == 8
     if n <= 128:
         assert g.bn == 128
+
+
+class _Dev:
+    def __init__(self, platform, kind):
+        self.platform, self.device_kind = platform, kind
+
+
+@pytest.mark.parametrize("platform,kind,want", [
+    ("cpu", "cpu", TPU_V5E),                  # described target off-chip
+    ("tpu", "TPU v5 lite", TPU_V5E),
+    ("tpu", "TPU v99 imaginary", ValueError),
+])
+def test_device_profile_by_device_kind(monkeypatch, platform, kind, want):
+    import jax
+    from repro.core.geometry import device_profile
+    monkeypatch.setattr(jax, "devices", lambda *a: [_Dev(platform, kind)])
+    if want is ValueError:
+        with pytest.raises(ValueError, match="v99"):
+            device_profile()
+    else:
+        assert device_profile() is want

@@ -125,7 +125,7 @@ def test_splitk_gemm(m, n, k, splits):
     geom = solve_block_geometry(m, n, k, SEW.E32, SEW.E32)
     epi = Epilogue(alpha=0.5, activation="relu")
     out = mte_gemm_splitk_pallas(a, b, geom=geom, n_split=splits,
-                                 epilogue=epi)
+                                 epilogue=epi, interpret=True)
     want = ref.mte_gemm(a, b, epilogue=epi)
     np.testing.assert_allclose(out, want, rtol=3e-5, atol=3e-5)
 
@@ -144,7 +144,7 @@ def test_splitk_nsplit_sweep_ragged_k(m, n, k, splits):
     geom = solve_block_geometry(m, n, k, SEW.E32, SEW.E32)
     epi = Epilogue(alpha=0.7, beta=1.3, has_bias=True, activation="gelu")
     out = mte_gemm_splitk_pallas(a, b, c, bias, geom=geom, n_split=splits,
-                                 epilogue=epi)
+                                 epilogue=epi, interpret=True)
     want = ref.mte_gemm(a, b, c, bias, epilogue=epi)
     np.testing.assert_allclose(out, want, rtol=3e-5, atol=3e-5)
 
@@ -160,7 +160,8 @@ def test_splitk_bf16_mixed_precision(splits):
     a, b = a.astype(jnp.bfloat16), b.astype(jnp.bfloat16)
     geom = solve_block_geometry(m, n, k, SEW.E16, SEW.E32)
     geom = dataclasses.replace(geom, transposed_b=False)
-    out = mte_gemm_splitk_pallas(a, b, geom=geom, n_split=splits)
+    out = mte_gemm_splitk_pallas(a, b, geom=geom, n_split=splits,
+                                 interpret=True)
     assert out.dtype == jnp.float32
     want = ref.mte_gemm(a, b)
     np.testing.assert_allclose(np.float32(out), np.float32(want),

@@ -29,6 +29,7 @@ def test_sharded_train_step_matches_single_device():
     import jax, jax.numpy as jnp, numpy as np, dataclasses
     from repro.configs import get_config
     from repro.distributed import sharding as sh
+    from repro.launch.mesh import make_host_mesh
     from repro.models import model as M
     from repro.optim.optimizer import AdamWConfig, init_opt_state
     from repro.training.trainer import make_train_step
@@ -47,9 +48,8 @@ def test_sharded_train_step_matches_single_device():
     _, _, m1 = jax.jit(step)(params, opt, batch)
 
     # 2x4 mesh, full sharding stack
-    mesh = jax.make_mesh((2, 4), ('data', 'model'))
-    from repro.distributed import compat
-    with compat.set_mesh(mesh):
+    mesh = make_host_mesh(2, 4)
+    with jax.set_mesh(mesh):
         pshape = jax.eval_shape(lambda: M.init_params(key, cfg))
         pspec = sh.param_specs(cfg, pshape, mesh)
         pshard = sh.named_shardings(mesh, pspec)
@@ -77,6 +77,7 @@ def test_moe_a2a_matches_scatter_path():
     out = _run("""
     import jax, jax.numpy as jnp, numpy as np, dataclasses
     from repro.configs import get_config
+    from repro.launch.mesh import make_host_mesh
     from repro.models import moe as moe_mod
 
     cfg = get_config('qwen3_moe_235b').reduced()
@@ -88,9 +89,8 @@ def test_moe_a2a_matches_scatter_path():
 
     want, aux1 = moe_mod.apply_moe(x, p, cfg)
 
-    mesh = jax.make_mesh((2, 4), ('data', 'model'))
-    from repro.distributed import compat
-    with compat.set_mesh(mesh):
+    mesh = make_host_mesh(2, 4)
+    with jax.set_mesh(mesh):
         got, aux2 = jax.jit(
             lambda x, p: moe_mod.apply_moe_a2a(x, p, cfg, mesh=mesh,
                                                token_axes=('data',)))(x, p)
@@ -109,6 +109,7 @@ def test_elastic_restore_reshards():
     from repro.checkpoint.manager import CheckpointManager
     from repro.configs import get_config
     from repro.distributed import sharding as sh
+    from repro.launch.mesh import make_host_mesh
     from repro.models import model as M
     from repro.optim.optimizer import init_opt_state
 
@@ -118,9 +119,8 @@ def test_elastic_restore_reshards():
     key = jax.random.PRNGKey(0)
     tmp = tempfile.mkdtemp()
 
-    mesh1 = jax.make_mesh((1, 8), ('data', 'model'))
-    from repro.distributed import compat
-    with compat.set_mesh(mesh1):
+    mesh1 = make_host_mesh(1, 8)
+    with jax.set_mesh(mesh1):
         pshape = jax.eval_shape(lambda: M.init_params(key, cfg))
         shard1 = sh.named_shardings(mesh1, sh.param_specs(cfg, pshape, mesh1))
         params = jax.jit(lambda k: M.init_params(k, cfg),
@@ -129,8 +129,8 @@ def test_elastic_restore_reshards():
         mgr = CheckpointManager(tmp)
         mgr.save(5, params, opt)
 
-    mesh2 = jax.make_mesh((4, 2), ('data', 'model'))
-    with compat.set_mesh(mesh2):
+    mesh2 = make_host_mesh(4, 2)
+    with jax.set_mesh(mesh2):
         oshape = jax.eval_shape(init_opt_state, pshape)
         shard2p = sh.named_shardings(mesh2, sh.param_specs(cfg, pshape, mesh2))
         shard2o = {'m': shard2p, 'v': shard2p,
@@ -155,6 +155,44 @@ def test_multipod_mesh_axes():
         {'data': 2, 'model': 4}
     mesh2 = make_elastic_mesh(6, model_parallel=4)  # degraded fleet
     assert mesh2.devices.size == 6
+    print('OK')
+    """)
+    assert "OK" in out
+
+
+def test_train_loop_shards_optimizer_state():
+    """Adam's moments are laid out like the parameters they track: on a
+    2×4 mesh no moment of the embedding table sits whole on one device
+    (at gemma-2b width a replicated moment does not fit a chip)."""
+    out = _run("""
+    import jax, dataclasses
+    from repro.configs import get_config
+    from repro.launch import train
+    from repro.launch.mesh import make_host_mesh
+
+    cfg = get_config('gemma_2b').reduced()
+    cfg = dataclasses.replace(cfg, n_layers=2, d_model=64, d_ff=128,
+                              vocab=256, n_heads=4, n_kv_heads=1, head_dim=16)
+    table = (cfg.vocab, cfg.d_model)
+    seen = []
+    make_step = train.make_train_step
+
+    def recording_step(*args):
+        step = make_step(*args)
+
+        def traced(params, opt_state, batch):
+            # Traced on the first call, while the initial parameters and
+            # both moments are live.
+            seen.extend(a.sharding for a in jax.live_arrays()
+                        if a.shape == table)
+            return step(params, opt_state, batch)
+        return traced
+
+    train.make_train_step = recording_step
+    train.train_loop(cfg, steps=1, batch=2, seq=16,
+                     mesh=make_host_mesh(2, 4), log=lambda msg: None)
+    assert len(seen) == 3, seen
+    assert not any(s.is_fully_replicated for s in seen), seen
     print('OK')
     """)
     assert "OK" in out

@@ -169,6 +169,22 @@ def test_chunk_exception_contained_to_one_request(setup):
     eng.sched.pool.audit()
 
 
+def test_chunk_compile_error_propagates(setup, monkeypatch):
+    """A prefill program that fails to compile is a defect of the
+    engine, not of one request: it must escape ``run()`` instead of
+    being turned into per-request error statuses."""
+    cfg, params, prompts = setup
+    eng = _engine(params, cfg)
+
+    def broken(*args):
+        raise RuntimeError("Mosaic failed to compile TPU kernel")
+
+    monkeypatch.setattr(eng, "_chunk_fn", lambda fmt, c: broken)
+    eng.submit(Request(rid=0, prompt=prompts[0], max_tokens=4))
+    with pytest.raises(RuntimeError, match="Mosaic"):
+        eng.run()
+
+
 def test_alloc_failure_defers_without_corruption(setup):
     """An injected page-allocation failure exercises the deferral /
     eviction path; every request still completes and never-preempted

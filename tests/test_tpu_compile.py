@@ -1,0 +1,147 @@
+"""Main-path kernels compiled for a described TPU v5e at gemma-2b widths.
+
+Nothing runs: each test lowers one kernel with ``interpret=False`` for a
+chip that is described, not attached, and checks that the TPU compiler
+accepts it and that a Mosaic kernel (``tpu_custom_call``) is in the
+program.  This is what the Pallas interpreter cannot show: misaligned
+blocks, unsupported layouts and VMEM overruns are refused here as they
+would be on the chip.
+
+The topology is described inside a fixture, never at import: only one
+process at a time may load the TPU library, and every test worker
+imports this file.  JAX's persistent compilation cache is off around
+these compiles, since a program compiled for a described chip is written
+to it but cannot be read back without one.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import autotune
+
+# gemma-2b (configs/gemma_2b.py) served with the launcher's defaults.
+D_MODEL, D_FF, N_HEADS, HEAD_DIM = 2048, 16384, 8, 256
+SLOTS, CHUNK, PAGE, CACHE_LEN = 4, 128, 16, 512
+RGLRU_WIDTH = 4096                      # recurrentgemma-9b's RG-LRU width
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        try:
+            desc = topologies.get_topology_desc(platform="tpu",
+                                                topology_name="v5e:2x2")
+        except Exception as e:
+            pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+        yield desc
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_on)
+        compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compiled_text(fn, one_chip, *shapes):
+    args = [jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+            for shape, dtype in shapes]
+    return jax.jit(fn).lower(*args).compile().as_text()
+
+
+@pytest.mark.parametrize("m,n,k,route", [
+    (CHUNK, D_FF, D_MODEL, "mte"),      # prefill-chunk up/gate projection
+    (SLOTS, D_FF, D_MODEL, "mte"),      # decode up/gate projection
+    (SLOTS, D_MODEL, D_FF, "splitk"),   # decode down projection, M=4
+])
+def test_planned_gemm_compiles(one_chip, m, n, k, route):
+    """bf16 operands, f32 accumulation, bf16 out — the signature a
+    ``dense`` layer under the bf16 format policy asks the plan cache for."""
+    plan = autotune.get_plan(m, n, k, jnp.bfloat16, jnp.bfloat16,
+                             fmt="bf16")
+    assert plan.route == route, plan.describe()
+    text = _compiled_text(
+        lambda a, b: autotune.execute_plan(plan, a, b, interpret=False),
+        one_chip, ((m, k), jnp.bfloat16), ((k, n), jnp.bfloat16))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.int8])
+def test_splitk_masked_tail_compiles(one_chip, dtype):
+    """Three K slices of the decode down projection leave a ragged last
+    slice, so the kernel masks its K tail on a packed 4-row block."""
+    from repro.core.geometry import solve_block_geometry
+    from repro.core.tile_state import SEW
+    from repro.kernels.splitk_gemm import mte_gemm_splitk_pallas
+    geom = solve_block_geometry(SLOTS, D_MODEL, D_FF, SEW.from_dtype(dtype),
+                                SEW.E32)
+    text = _compiled_text(
+        lambda a, b: mte_gemm_splitk_pallas(a, b, geom=geom, n_split=3,
+                                            interpret=False),
+        one_chip, ((SLOTS, D_FF), dtype), ((D_FF, D_MODEL), dtype))
+    assert "tpu_custom_call" in text
+
+
+def test_grouped_decode_qkv_compiles(one_chip):
+    """The decode step's q/k/v GEMVs as one grouped launch over the
+    stacked (3, D, Nmax) weight."""
+    plan = autotune.get_plan(SLOTS, N_HEADS * HEAD_DIM, D_MODEL,
+                             jnp.bfloat16, jnp.bfloat16, group=3, fmt="bf16")
+    assert plan.route == "grouped", plan.describe()
+    text = _compiled_text(
+        lambda x, w: autotune.execute_plan(plan, x, w, interpret=False),
+        one_chip, ((3, SLOTS, D_MODEL), jnp.bfloat16),
+        ((3, D_MODEL, N_HEADS * HEAD_DIM), jnp.bfloat16))
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kv_dtype", [jnp.bfloat16, jnp.int8])
+def test_flash_decode_paged_compiles(one_chip, kv_dtype):
+    """Paged decode attention over 16-token pages (MQA, head_dim 256);
+    int8 pages carry per-slot scales dequantized in the kernel."""
+    from repro.kernels.flash_decode import flash_decode_paged_pallas
+    max_pages = CACHE_LEN // PAGE
+    n_pages = SLOTS * max_pages + 1
+    pages = ((n_pages, PAGE, 1, HEAD_DIM), kv_dtype)
+    shapes = [((SLOTS, N_HEADS, HEAD_DIM), jnp.bfloat16), pages, pages,
+              ((SLOTS, max_pages), jnp.int32), ((SLOTS,), jnp.int32)]
+    if kv_dtype == jnp.int8:
+        shapes += [((n_pages, PAGE, 1, 1), jnp.float32)] * 2
+    text = _compiled_text(
+        lambda *a: flash_decode_paged_pallas(*a, interpret=False),
+        one_chip, *shapes)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("kv_len", [CHUNK, 2 * CHUNK])
+def test_flash_attention_compiles(one_chip, kv_len):
+    """Prefill-chunk attention: 128 queries against the first and the
+    second chunk's keys."""
+    from repro.kernels.flash_attention import flash_attention_pallas
+    text = _compiled_text(
+        lambda q, k, v: flash_attention_pallas(q, k, v, causal=True,
+                                               interpret=False),
+        one_chip, ((1, N_HEADS, CHUNK, HEAD_DIM), jnp.bfloat16),
+        ((1, 1, kv_len, HEAD_DIM), jnp.bfloat16),
+        ((1, 1, kv_len, HEAD_DIM), jnp.bfloat16))
+    assert "tpu_custom_call" in text
+
+
+def test_rglru_scan_compiles(one_chip):
+    from repro.kernels.rglru_scan import rglru_scan_pallas
+    text = _compiled_text(
+        lambda a, b: rglru_scan_pallas(a, b, interpret=False),
+        one_chip, ((1, CHUNK, RGLRU_WIDTH), jnp.float32),
+        ((1, CHUNK, RGLRU_WIDTH), jnp.float32))
+    assert "tpu_custom_call" in text
