@@ -24,6 +24,7 @@ engine quarantine that slot while every healthy request still finishes::
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
@@ -31,8 +32,8 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.launch.compile_cache import enable_compile_cache
-from repro.models import model as model_lib
-from repro.serving.engine import Request, ServingEngine
+from repro.serving.engine import (Request, ServingEngine,
+                                  init_serving_params)
 from repro.serving.resilience import FaultInjector, Shed
 
 
@@ -162,7 +163,6 @@ def main():
     if args.reduced:
         cfg = cfg.reduced()
     if args.no_graph:
-        import dataclasses
         cfg = dataclasses.replace(cfg, use_graph=False)
 
     draft_cfg = None
@@ -187,10 +187,17 @@ def main():
         from repro.telemetry.slo import SloMonitor
         slo_monitor = SloMonitor()
 
-    # Passed straight through: the engine holds its own serving copy of
-    # the weights, and nothing here keeps the initialised ones alive.
-    engine = ServingEngine(model_lib.init_params(jax.random.PRNGKey(0), cfg),
-                           cfg, slots=args.slots,
+    # Initialised at the operand width every reader of the weights
+    # rounds them to (bf16 under a bf16 policy), so the fp32 tree never
+    # exists on the device; a wider or quantized draft keeps them fp32.
+    readers = [cfg]
+    if draft_cfg is not None:
+        readers.append(draft_cfg)
+    if args.draft_format:
+        readers.append(dataclasses.replace(cfg,
+                                           format_policy=args.draft_format))
+    params = init_serving_params(jax.random.PRNGKey(0), *readers)
+    engine = ServingEngine(params, cfg, slots=args.slots,
                            cache_len=args.cache_len,
                            prefill_len=args.prefill_len,
                            page_size=args.page_size,

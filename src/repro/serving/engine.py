@@ -83,7 +83,9 @@ Precision: as before, ``format_policy=`` overrides the model config's
 policy; a request may name its own prefill policy
 (``Request(format_policy="int8")``).  The GEMM plan cache keys plans per
 format, so the JSON warm start (``plan_cache_path=``) restores
-format-keyed plans — including the grouped decode signature.
+format-keyed plans — including the grouped decode signature.  Every
+policy computes with the parameters as given; a caller that narrows
+them (:func:`serving_params`) names every policy that will read them.
 
 **Failure model** (see :mod:`repro.serving.resilience`): requests fail
 *individually*, the batch keeps decoding.  ``run()`` returns
@@ -130,28 +132,38 @@ from repro.serving.scheduler import ContinuousBatchingScheduler
 from repro.telemetry import tracing
 from repro.telemetry.registry import registry as metrics_registry
 
-__all__ = ["Request", "ServingEngine", "serving_params"]
+__all__ = ["Request", "ServingEngine", "init_serving_params",
+           "serving_params"]
 
 
-def serving_params(params, cfg: ArchConfig):
-    """The parameters as the engine holds them: GEMM weights (``w``
-    leaves) and the embedding table / LM head stored at the format
-    policy's operand width.
+def serving_params(params, *readers: ArchConfig):
+    """``params`` with the GEMM weights (``w`` leaves) and the embedding
+    table / LM head narrowed to the operand width every reader rounds
+    them to.
 
-    The cast applies only when it is exact for every reader: under an
-    unquantized policy whose operand dtype is the compute dtype, each
-    consumer (the GEMM's operand cast, ``embed``'s cast to the compute
-    dtype, ``unembed``'s cast to the operand dtype) rounds these leaves
-    to that same dtype first.  Holding them narrow then changes no
-    result, and gemma-2b's bf16 weights take 5 GB of the chip's 16 GB
-    instead of 10 GB plus a converted copy inside every program.
-    Norms, biases and every other leaf keep their dtype.
+    ``readers`` are the configs whose format policies will compute with
+    these parameters: the engine's, a weight-shared draft's, and any
+    per-request prefill policy.  The cast applies only when it is exact
+    for all of them: each is unquantized with an operand dtype equal to
+    its compute dtype, and they agree on it.  Each consumer (the GEMM's
+    operand cast, ``embed``'s cast to the compute dtype, ``unembed``'s
+    cast to the operand dtype) then rounds these leaves to that dtype
+    first, so holding them narrow changes no result.  If any reader
+    would see more bits (fp32) or quantizes from the weights (int8), the
+    tree is returned unchanged.  Norms, biases and every other leaf keep
+    their dtype.
     """
     from repro.models.layers import model_format
-    fmt = model_format(cfg)
-    narrow = jnp.dtype(fmt.operand_jnp)
-    if fmt.quantized or narrow != jnp.dtype(cfg.compute_dtype):
+    widths = set()
+    for cfg in readers:
+        fmt = model_format(cfg)
+        width = jnp.dtype(fmt.operand_jnp)
+        if fmt.quantized or width != jnp.dtype(cfg.compute_dtype):
+            return params
+        widths.add(width)
+    if len(widths) != 1:
         return params
+    (narrow,) = widths
 
     def hold(path, x):
         name = getattr(path[-1], "key", None)
@@ -162,6 +174,17 @@ def serving_params(params, cfg: ArchConfig):
         return x
 
     return jax.tree_util.tree_map_with_path(hold, params)
+
+
+def init_serving_params(key, cfg: ArchConfig, *readers: ArchConfig):
+    """Random parameters of ``cfg`` from ``key``, initialised straight
+    into :func:`serving_params` form for ``cfg`` and ``readers``.
+
+    One program does both, so the fp32 tree never exists on the device:
+    gemma-2b's takes 10 GB of a v5e's 16 GB, its bf16 serving form 5 GB.
+    """
+    return jax.jit(lambda k: serving_params(model_lib.init_params(k, cfg),
+                                            cfg, *readers))(key)
 
 
 def _stack_decode_qkv(params):
@@ -258,7 +281,6 @@ class ServingEngine:
         cfg = dataclasses.replace(cfg, cache_quant=False,
                                   kv_cache_format=kv_format,
                                   decode_qkv_grouped=bool(grouped_qkv))
-        params = serving_params(params, cfg)
         if grouped_qkv:
             params = _stack_decode_qkv(params)
         self.params = params

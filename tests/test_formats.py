@@ -424,3 +424,65 @@ def test_serving_per_request_format(tmp_path):
     # ...and a typo'd policy fails at submit, not inside the batch loop.
     with pytest.raises(ValueError):
         eng.submit(Request(rid=3, prompt=prompt, format_policy="fp8"))
+
+
+def _prefill_logits(eng, fmt, tokens):
+    """Logits of the engine's first prefill chunk under policy ``fmt``."""
+    batch = eng._make_batch(tokens[None], table=eng.sched.table_row(0)[None],
+                            slot=0)
+    logits, _ = eng._chunk_fn(fmt, 0)(eng.params, batch, eng.cache)
+    return np.asarray(logits, np.float32)
+
+
+def test_serving_fp32_request_reads_the_weights_as_given():
+    """An fp32 request on a bf16 engine computes with the fp32 weights it
+    was given: its prefill logits equal those of an fp32 engine."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import model as model_lib
+    from repro.serving.engine import ServingEngine
+
+    cfg = dataclasses.replace(get_config("gemma_2b").reduced(),
+                              n_layers=2, vocab=128, compute_dtype="bfloat16")
+    params = model_lib.init_params(jax.random.PRNGKey(0), cfg)
+    kw = dict(slots=1, cache_len=64, prefill_len=16)
+    bf16 = ServingEngine(params, cfg, format_policy="bf16", **kw)
+    fp32 = ServingEngine(params, cfg, format_policy="fp32", **kw)
+    tokens = np.asarray(RNG.integers(0, 128, 16), np.int32)
+    got = _prefill_logits(bf16, "fp32", tokens)
+    np.testing.assert_array_equal(got, _prefill_logits(fp32, None, tokens))
+    assert not np.array_equal(got, _prefill_logits(bf16, None, tokens))
+
+
+@pytest.mark.parametrize("others, narrowed", [
+    ((), True), (("bf16",), True), (("fp32",), False), (("int8",), False)])
+def test_serving_params_narrow_only_for_every_reader(others, narrowed):
+    """Weights go to bf16 only when every policy that reads them rounds
+    them to bf16 first; a narrowed tree serves bf16 exactly as fp32."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import model as model_lib
+    from repro.serving.engine import init_serving_params, serving_params
+
+    cfg = dataclasses.replace(get_config("gemma_2b").reduced(),
+                              n_layers=2, vocab=128, format_policy="bf16",
+                              compute_dtype="bfloat16")
+    readers = [dataclasses.replace(cfg, format_policy=f) for f in others]
+    key = jax.random.PRNGKey(0)
+    params = model_lib.init_params(key, cfg)
+    held = serving_params(params, cfg, *readers)
+    want = jnp.bfloat16 if narrowed else jnp.float32
+    assert held["embedding"]["table"].dtype == want
+    assert held["groups"][0]["ffn"]["up"]["w"].dtype == want
+    assert held["final_norm"]["scale"].dtype == jnp.float32
+    assert jax.tree.map(lambda x: x.dtype,
+                        init_serving_params(key, cfg, *readers)) == \
+        jax.tree.map(lambda x: x.dtype, held)
+    if narrowed:
+        tokens = jnp.asarray(RNG.integers(0, 128, (1, 16)), jnp.int32)
+        run = jax.jit(lambda p: model_lib.prefill(p, {"tokens": tokens},
+                                                  cfg)[0])
+        np.testing.assert_array_equal(np.asarray(run(held), np.float32),
+                                      np.asarray(run(params), np.float32))
