@@ -48,8 +48,7 @@ scheduling work (deadlines, admission, prefill chunks) while the
 previous step's decode is still on device, then retires that step — the
 ONE intentional blocking ``device_get`` per step — re-admits into any
 slot the delivery freed, and launches its own decode.  Delivery
-therefore lags launch by one step (``scheduler.delivery_lag_mean``,
-``serving.steps_in_flight`` / ``serving.results_stale_steps`` gauges,
+therefore lags launch by one step (``scheduler.delivery_lag_mean``
 and a staleness note in ``telemetry.export.health()`` make the lag
 observable).  The pipeline flushes wherever host-visible output state
 is read or rewritten: sequence horizon, speculation, imminent eviction,
@@ -149,7 +148,12 @@ on or off, test-asserted):
   ``with tracing.current().span("phase"):`` — when no tracer is
   installed this is the allocation-free no-op singleton, so
   instrumentation costs nothing; never place a span inside a jitted
-  function (it would time jax tracing, not execution).  Request
+  function (it would time jax tracing, not execution).  An installed
+  tracer's spans are also ``jax.profiler`` annotations, so a profile
+  shows the step's host phases (``step``, ``admit``, ``prefill_chunk``,
+  ``device_wait``, ``sample``, ``evict``, ``decode_launch``,
+  ``observe``) on the device's clock beside the ``jit_serve_*``
+  programs.  Request
   lifecycle instants flow through the scheduler's ``_note_event`` choke
   point; fault firings emit ``fault.*`` instants.
   ``launch/serve.py --trace PATH`` exports Chrome/Perfetto

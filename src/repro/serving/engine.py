@@ -183,8 +183,10 @@ def init_serving_params(key, cfg: ArchConfig, *readers: ArchConfig):
     One program does both, so the fp32 tree never exists on the device:
     gemma-2b's takes 10 GB of a v5e's 16 GB, its bf16 serving form 5 GB.
     """
-    return jax.jit(lambda k: serving_params(model_lib.init_params(k, cfg),
-                                            cfg, *readers))(key)
+    def serve_init_params(k):
+        return serving_params(model_lib.init_params(k, cfg), cfg, *readers)
+
+    return jax.jit(serve_init_params)(key)
 
 
 def _stack_decode_qkv(params):
@@ -365,18 +367,23 @@ class ServingEngine:
                                if self.async_steps else 1)
         self._inflight: Deque[dict] = collections.deque()
         self._flushing = False
-        self._inflight_peak = 0        # deepest pipeline this step
         self.steps_in_flight_max = 0   # deepest pipeline ever (bench row)
         self._last_tok = jnp.zeros((slots, 1), jnp.int32)
         self._zero_key = jax.random.PRNGKey(0)  # greedy rows: no stream use
-        self._decode_step = jax.jit(
-            lambda p, b, c, key, temps, active: model_lib.decode_and_sample(
+
+        # Every jitted serving program is a named function: the profiler
+        # shows it as ``jit_<name>`` on the device's XLA Modules line.
+        def serve_decode(p, b, c, key, temps, active):
+            return model_lib.decode_and_sample(
                 p, b, c, self.cfg, key=key, temperatures=temps,
-                active_rows=active),
-            donate_argnums=(2,))
+                active_rows=active)
+
+        def serve_scatter_token(lt, tok, slot):
+            return lt.at[slot, 0].set(tok)
+
+        self._decode_step = jax.jit(serve_decode, donate_argnums=(2,))
         self._seed_sample = jax.jit(model_lib.sample_token)
-        self._scatter_tok = jax.jit(
-            lambda lt, tok, slot: lt.at[slot, 0].set(tok))
+        self._scatter_tok = jax.jit(serve_scatter_token)
 
         # -- prefix-index persistence (cross-engine prefix cache) --------------
         # JSON of the pool's published (page, hash) pairs, saved next to
@@ -442,13 +449,19 @@ class ServingEngine:
             self.draft_cache = model_lib.init_paged_cache(
                 dcfg, slots, cache_len, num_pages=slots * maxp + 1,
                 page_size=page_size)
-            self._draft_decode = jax.jit(
-                lambda p, b, c: model_lib.decode(p, b, c, self.draft_cfg))
-            self._draft_verify = jax.jit(
-                lambda p, b, c: model_lib.verify_chunk(p, b, c,
-                                                       self.draft_cfg))
-            self._verify = jax.jit(
-                lambda p, b, c: model_lib.verify_chunk(p, b, c, self.cfg))
+
+            def serve_draft_decode(p, b, c):
+                return model_lib.decode(p, b, c, self.draft_cfg)
+
+            def serve_draft_verify(p, b, c):
+                return model_lib.verify_chunk(p, b, c, self.draft_cfg)
+
+            def serve_verify(p, b, c):
+                return model_lib.verify_chunk(p, b, c, self.cfg)
+
+            self._draft_decode = jax.jit(serve_draft_decode)
+            self._draft_verify = jax.jit(serve_draft_verify)
+            self._verify = jax.jit(serve_verify)
             self._draft_chunk_fns: Dict[int, object] = {}
             self._spec_program = None
             if self.cfg.use_graph:
@@ -528,8 +541,11 @@ class ServingEngine:
                                        format_policy=format_policy)
                    if format_policy is not None else self.cfg)
             pos0 = chunk_idx * self.prefill_chunk
-            fn = jax.jit(lambda p, b, c, _cfg=cfg, _p0=pos0:
-                         model_lib.prefill_chunk(p, b, c, _cfg, pos0=_p0))
+
+            def serve_prefill_chunk(p, b, c):
+                return model_lib.prefill_chunk(p, b, c, cfg, pos0=pos0)
+
+            fn = jax.jit(serve_prefill_chunk)
             per_fmt[chunk_idx] = fn
         return fn
 
@@ -743,9 +759,18 @@ class ServingEngine:
         depth land in the metrics registry as ``kv.*`` / ``serving.*``
         gauges and the optional :class:`SloMonitor` evaluates its
         objectives — all pure host-side bookkeeping, after the step's
-        device work is already submitted."""
-        self._step_impl()
-        self._observe_step()
+        device work is already submitted.
+
+        Host spans, strictly nested under ``step``: ``admit``,
+        ``prefill_chunk``, ``device_wait`` (the blocking fetch of a
+        delivered step), ``sample`` (its host bookkeeping), ``evict``,
+        ``decode_launch`` and ``observe``; ``draft`` and ``verify`` on
+        the speculative path."""
+        tr = tracing.current()
+        with tr.span("step"):
+            self._step_impl()
+            with tr.span("observe"):
+                self._observe_step()
 
     def _observe_step(self):
         """Publish per-step pool/scheduler state and evaluate SLOs.
@@ -763,12 +788,6 @@ class ServingEngine:
             self.sched.cancelled_requests)
         reg.gauge("serving.finished_requests").set(
             self.sched.completed_requests + self.sched.cancelled_requests)
-        # Pipeline staleness: with async stepping, the counters above
-        # describe the last *delivered* step — up to ``pipeline_depth - 1``
-        # steps of device work are still in flight and intentionally NOT
-        # reported as finished (health() carries the same note).
-        reg.gauge("serving.steps_in_flight").set(self._inflight_peak)
-        reg.gauge("serving.results_stale_steps").set(self.steps_in_flight)
         if self.slo_monitor is not None:
             self.slo_monitor.observe(step=self.step_idx)
 
@@ -803,7 +822,6 @@ class ServingEngine:
         row (logit poison); non-finite logits quarantine only their slot.
         """
         self.step_idx += 1
-        self._inflight_peak = self.steps_in_flight
         if self.fault is not None:
             # May raise EngineCrash (supervised restart path) or arm a
             # pool allocation failure / sleep through a straggle.
@@ -904,48 +922,55 @@ class ServingEngine:
         inputs are the carried device-side last-token array (updated
         *inside* the previous launch), and pos/table/temps are host
         scheduler state."""
-        table = np.full((self.slots, self.sched.max_pages_per_seq), -1,
-                        np.int32)
-        temps = np.zeros(self.slots, np.float32)
-        active = np.zeros(self.slots, bool)
-        for slot in decoding:
-            table[slot] = self.sched.table_row(slot)
-            temps[slot] = max(0.0, float(self.slot_req[slot].temperature))
-            active[slot] = True
-        if temps.any():
-            self._key, key = jax.random.split(self._key)
-        else:
-            key = self._zero_key   # all-greedy: the key stream is untouched
-        # Row-valid mask: ring/recurrent cache rows of slots that are
-        # not decoding this step keep their prior state inside the
-        # decode program itself.  Always passed for stateful archs so
-        # the jit signature is stable.  ``slot_pos`` is copied: the
-        # device array may alias the host buffer until the launch has
-        # read it, and the positions advance in place just below.
-        batch = self._make_batch(
-            self._last_tok, pos=self.slot_pos.copy(), table=table,
-            row_valid=active if self._stateful_rows else None)
-        # The decode span stays open until delivery: it covers the
-        # device-resident window, so async traces show decode visibly
-        # overlapping the NEXT step's admit/prefill/sample host spans.
-        span = tracing.current().span(
-            "decode", args={"step": self.step_idx, "rows": len(decoding)})
-        span.__enter__()
-        tok, finite, logits, self._last_tok, self.cache = self._decode_step(
-            self.params, batch, self.cache, key, jnp.asarray(temps),
-            jnp.asarray(active))
-        self._inflight.append({
-            "kind": "decode", "step": self.step_idx, "span": span,
-            "slots": list(decoding),
-            "reqs": {s: self.slot_req[s] for s in decoding},
-            "pos_after": {s: int(self.slot_pos[s]) + 1 for s in decoding},
-            "tok": tok, "finite": finite, "logits": logits,
-        })
-        for slot in decoding:
-            self.slot_pos[slot] += 1
-        self._inflight_peak = max(self._inflight_peak, self.steps_in_flight)
-        self.steps_in_flight_max = max(self.steps_in_flight_max,
-                                       self.steps_in_flight)
+        with tracing.current().span("decode_launch"):
+            table = np.full((self.slots, self.sched.max_pages_per_seq), -1,
+                            np.int32)
+            temps = np.zeros(self.slots, np.float32)
+            active = np.zeros(self.slots, bool)
+            for slot in decoding:
+                table[slot] = self.sched.table_row(slot)
+                temps[slot] = max(0.0, float(self.slot_req[slot].temperature))
+                active[slot] = True
+            if temps.any():
+                self._key, key = jax.random.split(self._key)
+            else:
+                key = self._zero_key   # all-greedy: key stream untouched
+            # Row-valid mask: ring/recurrent cache rows of slots that are
+            # not decoding this step keep their prior state inside the
+            # decode program itself.  Always passed for stateful archs so
+            # the jit signature is stable.  ``slot_pos`` is copied: the
+            # device array may alias the host buffer until the launch has
+            # read it, and the positions advance in place just below.
+            batch = self._make_batch(
+                self._last_tok, pos=self.slot_pos.copy(), table=table,
+                row_valid=active if self._stateful_rows else None)
+            # The decode span stays open until delivery: it covers the
+            # device-resident window, so async traces show decode visibly
+            # overlapping the NEXT step's admit/prefill/sample host spans.
+            # Not host work, so not a profiler annotation: the device's own
+            # module events show that interval in a profile.
+            tr = tracing.active()
+            span = None
+            if tr is not None:
+                span = tr.span("decode", annotate=False, args={
+                    "step": self.step_idx, "rows": len(decoding)})
+                span.__enter__()
+            (tok, finite, logits, self._last_tok,
+             self.cache) = self._decode_step(
+                self.params, batch, self.cache, key, jnp.asarray(temps),
+                jnp.asarray(active))
+            self._inflight.append({
+                "kind": "decode", "step": self.step_idx, "span": span,
+                "slots": list(decoding),
+                "reqs": {s: self.slot_req[s] for s in decoding},
+                "pos_after": {s: int(self.slot_pos[s]) + 1
+                              for s in decoding},
+                "tok": tok, "finite": finite, "logits": logits,
+            })
+            for slot in decoding:
+                self.slot_pos[slot] += 1
+            self.steps_in_flight_max = max(self.steps_in_flight_max,
+                                           self.steps_in_flight)
 
     def _drain_to_depth(self):
         """Deliver in-flight results down to the pipeline's depth bound.
@@ -1014,7 +1039,8 @@ class ServingEngine:
         discarded: their device-side write went to pages that are either
         still owned or fully rewritten by a later owner's prefill
         (launch order == device execution order)."""
-        tok, finite = jax.device_get((entry["tok"], entry["finite"]))
+        with tracing.current().span("device_wait"):
+            tok, finite = jax.device_get((entry["tok"], entry["finite"]))
         tok = np.asarray(tok).copy()
         finite = np.asarray(finite).copy()
         if self.fault is not None:
@@ -1071,7 +1097,8 @@ class ServingEngine:
         sample): the first token of a freshly prefilled request."""
         slot = entry["slots"][0]
         req = entry["reqs"][slot]
-        tok, finite = jax.device_get((entry["tok"], entry["finite"]))
+        with tracing.current().span("device_wait"):
+            tok, finite = jax.device_get((entry["tok"], entry["finite"]))
         if req.done or self.slot_req[slot] is not req:
             return
         if self.quarantine and not bool(np.asarray(finite).reshape(-1)[0]):
@@ -1171,8 +1198,6 @@ class ServingEngine:
                 "slots": [slot], "reqs": {slot: req},
                 "tok": tok, "finite": finite,
             })
-            self._inflight_peak = max(self._inflight_peak,
-                                      self.steps_in_flight)
             self.steps_in_flight_max = max(self.steps_in_flight_max,
                                            self.steps_in_flight)
 
@@ -1241,8 +1266,12 @@ class ServingEngine:
         fn = self._draft_chunk_fns.get(chunk_idx)
         if fn is None:
             pos0 = chunk_idx * self.prefill_chunk
-            fn = jax.jit(lambda p, b, c, _p0=pos0: model_lib.prefill_chunk(
-                p, b, c, self.draft_cfg, pos0=_p0))
+
+            def serve_draft_prefill_chunk(p, b, c):
+                return model_lib.prefill_chunk(p, b, c, self.draft_cfg,
+                                               pos0=pos0)
+
+            fn = jax.jit(serve_draft_prefill_chunk)
             self._draft_chunk_fns[chunk_idx] = fn
         return fn
 

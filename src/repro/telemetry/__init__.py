@@ -24,7 +24,9 @@ inside jitted code; timestamps are taken only at host sync points):
   "displayTimeUnit": "ms"}``, spans as phase-``X`` complete events
   (``ts``/``dur`` in microseconds), lifecycle/fault marks as
   phase-``i`` instants — load the file directly in ``ui.perfetto.dev``
-  or ``chrome://tracing``.
+  or ``chrome://tracing``.  Its spans are also ``jax.profiler``
+  annotations (jax imported when a tracer is made), so a profiler
+  session records them on the device trace's clock.
 - :mod:`repro.telemetry.gemm_account` — per-GEMM dispatch accounting at
   the same seams ``repro.graph.trace.trace_gemms()`` hooks
   (``dispatch.mte_gemm``, ``kernels/ops.py``, compiled-program node

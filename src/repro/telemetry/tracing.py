@@ -21,7 +21,12 @@ A real :class:`Tracer` records **Chrome/Perfetto trace-event JSON**
 - ``span(name)`` -> one phase-``X`` *complete* event per exit, with
   ``ts`` (begin) and ``dur`` in integer microseconds relative to tracer
   creation.  Nesting is positional: a child's ``[ts, ts+dur]`` interval
-  sits inside its parent's on the same ``pid``/``tid``.
+  sits inside its parent's on the same ``pid``/``tid``.  The span is
+  also a ``jax.profiler.TraceAnnotation`` of the same name for its
+  duration, so a profiler session records it in its ``.xplane.pb`` on
+  the device trace's clock.  ``span(name, annotate=False)`` is for an
+  interval that is not host work on the calling thread (a launched
+  program's residency on the device): it stays in the JSON only.
 - ``instant(name, args=...)`` -> one phase-``i`` instant event (request
   lifecycle marks, fault firings).
 
@@ -47,21 +52,28 @@ _TID = 1
 
 
 class _Span:
-    """Context manager for one complete ('X') event."""
+    """Context manager for one complete ('X') event, and the profiler
+    annotation of the same name unless ``annotation`` is None."""
 
-    __slots__ = ("_tracer", "_name", "_args", "_t0")
+    __slots__ = ("_tracer", "_name", "_args", "_t0", "_annotation")
 
-    def __init__(self, tracer: "Tracer", name: str, args: Optional[dict]):
+    def __init__(self, tracer: "Tracer", name: str, args: Optional[dict],
+                 annotation=None):
         self._tracer = tracer
         self._name = name
         self._args = args
+        self._annotation = annotation
 
     def __enter__(self):
+        if self._annotation is not None:
+            self._annotation.__enter__()
         self._t0 = self._tracer._now_us()
         return self
 
     def __exit__(self, *exc):
         t1 = self._tracer._now_us()
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
         ev = {"name": self._name, "ph": "X", "ts": self._t0,
               "dur": max(0, t1 - self._t0), "pid": _PID, "tid": _TID,
               "cat": "engine"}
@@ -106,6 +118,10 @@ class Tracer:
     enabled = True
 
     def __init__(self, clock: Optional[Callable[[], float]] = None):
+        # Imported here, not at module level: the package stays free of
+        # jax imports, and only a tracer that records pays for one.
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
         self._clock = clock or time.perf_counter
         self._t0 = self._clock()
         self.events: List[Dict] = []
@@ -113,8 +129,10 @@ class Tracer:
     def _now_us(self) -> int:
         return int((self._clock() - self._t0) * 1e6)
 
-    def span(self, name: str, args: Optional[dict] = None) -> _Span:
-        return _Span(self, name, args)
+    def span(self, name: str, args: Optional[dict] = None, *,
+             annotate: bool = True) -> _Span:
+        return _Span(self, name, args,
+                     self._annotation(name) if annotate else None)
 
     def instant(self, name: str, args: Optional[dict] = None) -> None:
         ev = {"name": name, "ph": "i", "ts": self._now_us(), "pid": _PID,

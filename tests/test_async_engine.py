@@ -219,6 +219,39 @@ def test_trace_decode_overlaps_next_step_host_work(setup):
     assert not tracing.span_overlaps(sync_doc, "decode", "prefill_chunk")
 
 
+def test_profile_holds_the_engine_host_spans_and_no_decode(setup,
+                                                          tmp_path):
+    """Under a profiler session with a tracer installed, the step's host
+    phases are annotations on the profiler's clock; the in-flight
+    ``decode`` interval (device residency, not host work) stays in the
+    JSON only, and every jitted serving program has a ``serve_`` name."""
+    cfg, params = setup
+    prompts, budgets = _workload(cfg, n_req=3)
+    eng = ServingEngine(params, cfg, slots=2, cache_len=64,
+                        prefill_len=16, page_size=8, prefill_chunk=8)
+    for rid, p in enumerate(prompts):
+        eng.submit(Request(rid=rid, prompt=p, max_tokens=budgets[rid]))
+    tr = tracing.install(tracing.Tracer())
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        eng.run()
+    finally:
+        jax.profiler.stop_trace()
+        tracing.uninstall()
+    (path,) = tmp_path.glob("plugins/profile/*/*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    names = {ev.name for plane in data.planes
+             if plane.name.startswith("/host:")
+             for line in plane.lines for ev in line.events}
+    assert {"step", "admit", "prefill_chunk", "device_wait", "sample",
+            "evict", "decode_launch", "observe"} <= names
+    assert "decode" not in names
+    assert "decode" in {e["name"] for e in tr.events}
+    programs = (eng._decode_step, eng._scatter_tok, eng._chunk_fn(None, 1))
+    assert [p.__name__ for p in programs] == [
+        "serve_decode", "serve_scatter_token", "serve_prefill_chunk"]
+
+
 def test_span_overlaps_and_validate_trace_unit():
     def ev(name, ts, dur):
         return {"name": name, "ph": "X", "ts": ts, "dur": dur,

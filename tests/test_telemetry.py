@@ -131,6 +131,52 @@ def test_noop_tracer_is_allocation_free_singleton():
     assert tracing.NOOP.instant("x", args={"k": 1}) is None
 
 
+def test_noop_spans_allocate_nothing_and_never_touch_the_profiler(
+        monkeypatch):
+    """With nothing installed, every engine span is the one prebuilt
+    no-op object, and no profiler annotation is made."""
+    def refuse(name):
+        raise AssertionError(f"NOOP opened a profiler annotation {name!r}")
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", refuse)
+    names = ("step", "admit", "prefill_chunk", "device_wait", "sample",
+             "evict", "decode_launch", "observe")
+    seen = set()
+    for name in names:
+        with tracing.current().span(name) as sp:
+            seen.add(id(sp))
+    assert seen == {id(tracing.NOOP.span("x"))}
+
+
+def _host_event_names(log_dir):
+    (path,) = log_dir.glob("plugins/profile/*/*.xplane.pb")
+    data = jax.profiler.ProfileData.from_file(str(path))
+    return {ev.name for plane in data.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for ev in line.events}
+
+
+def test_tracer_spans_are_profiler_annotations(tmp_path):
+    """An installed tracer's spans land in a profiler session's trace
+    under their own names; ``annotate=False`` keeps one in the JSON
+    only."""
+    tr = tracing.install(tracing.Tracer())
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with tracing.current().span("phase.outer"):
+            with tracing.current().span("phase.inner"):
+                pass
+        with tr.span("phase.on_device", annotate=False):
+            pass
+    finally:
+        jax.profiler.stop_trace()
+        tracing.uninstall()
+    names = _host_event_names(tmp_path)
+    assert {"phase.outer", "phase.inner"} <= names
+    assert "phase.on_device" not in names
+    assert [e["name"] for e in tr.events] == [
+        "phase.inner", "phase.outer", "phase.on_device"]
+
+
 def test_span_nesting_and_schema(tmp_path):
     t = [0.0]
 
