@@ -255,8 +255,13 @@ def _flash_bwd(causal, window, softcap, scale, interpret, res, g):
     s = scale if scale is not None else 1.0 / (d ** 0.5)
 
     def ref(q_, k_, v_):
-        return _xla_attention(q_, k_, v_, causal=causal, window=window,
-                              softcap=softcap, scale=s)
+        # The kernel's (B, H, S, D) operands; _xla_attention takes the
+        # (B, S, H, D) order.
+        def t(a):
+            return a.transpose(0, 2, 1, 3)
+
+        return t(_xla_attention(t(q_), t(k_), t(v_), causal=causal,
+                                window=window, softcap=softcap, scale=s))
 
     _, vjp = jax.vjp(ref, q, k, v)
     return vjp(g)

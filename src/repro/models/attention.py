@@ -194,9 +194,9 @@ _KV_CHUNK = 1024
 def _grouped_logits(q, k, scale, softcap):
     """QK logits without materializing repeated KV heads (GQA).
 
-    q: (B, Hkv, G, Sq, D); k: (B, Hkv, Skv, D) → (B, Hkv, G, Sq, Skv) f32.
+    q: (B, Hkv, G, Sq, D); k: (B, Skv, Hkv, D) → (B, Hkv, G, Sq, Skv) f32.
     """
-    logits = jnp.einsum("bngqd,bnkd->bngqk", q, k,
+    logits = jnp.einsum("bngqd,bknd->bngqk", q, k,
                         preferred_element_type=jnp.float32) * scale
     if softcap is not None:
         logits = softcap * jnp.tanh(logits / softcap)
@@ -215,8 +215,13 @@ def _mask(qp, kp, causal, window):
 def _xla_attention(q, k, v, *, causal, window, softcap, scale,
                    kv_positions=None, q_positions=None,
                    chunk: int = _KV_CHUNK):
-    """jnp attention (BHSD layout) with the same mask semantics as the
-    flash kernel; used in pjit graphs where Mosaic cannot lower on CPU.
+    """jnp attention with the same mask semantics as the flash kernel;
+    used in pjit graphs where Mosaic cannot lower on CPU.
+
+    q: (B, Sq, H, D); k/v: (B, Skv, Hkv, D) → (B, Sq, H, D): the axis
+    order in which projections, caches and gathered pool pages hold them.
+    K and V are contracted in that order; Q is regrouped to
+    (B, Hkv, G, Sq, D), the order of the grouped logits.
 
     GQA runs as a grouped einsum (KV heads never materialized H-wide).
     Long sequences switch to a KV-chunked online-softmax scan with an
@@ -224,11 +229,10 @@ def _xla_attention(q, k, v, *, causal, window, softcap, scale,
     expressed in XLA, which is what makes 32k-token prefill and 4k training
     of the large dense archs fit in HBM.
     """
-    b, h, sq, hd = q.shape
-    hkv = k.shape[1]
+    b, sq, h, hd = q.shape
+    skv, hkv = k.shape[1], k.shape[2]
     g = h // hkv
-    skv = k.shape[2]
-    qg = q.reshape(b, hkv, g, sq, hd)
+    qg = q.transpose(0, 2, 1, 3).reshape(b, hkv, g, sq, hd)
     # Normalize positions to batched (B, S) form (per-sequence decode
     # positions are what continuous batching needs).
     if q_positions is None:
@@ -242,37 +246,37 @@ def _xla_attention(q, k, v, *, causal, window, softcap, scale,
         out = _chunked_attention(qg, k, v, q_positions, kv_positions,
                                  causal=causal, window=window,
                                  softcap=softcap, scale=scale, chunk=chunk)
-        return out.reshape(b, h, sq, hd)
-
-    logits = _grouped_logits(qg, k, scale, softcap)
-    mask = _mask(q_positions[:, :, None], kv_positions[:, None, :],
-                 causal, window)
-    logits = jnp.where(mask[:, None, None], logits, _NEG_INF)
-    probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
-    out = jnp.einsum("bngqk,bnkd->bngqd", probs, v)
-    return out.reshape(b, h, sq, hd)
+    else:
+        logits = _grouped_logits(qg, k, scale, softcap)
+        mask = _mask(q_positions[:, :, None], kv_positions[:, None, :],
+                     causal, window)
+        logits = jnp.where(mask[:, None, None], logits, _NEG_INF)
+        probs = jax.nn.softmax(logits, axis=-1).astype(v.dtype)
+        out = jnp.einsum("bngqk,bknd->bngqd", probs, v)
+    return out.reshape(b, h, sq, hd).transpose(0, 2, 1, 3)
 
 
 def _chunked_attention(qg, k, v, q_positions, kv_positions, *, causal,
                        window, softcap, scale, chunk: int = _KV_CHUNK):
     """Online-softmax scan over KV chunks (flash semantics in XLA).
 
-    qg: (B, Hkv, G, Sq, D); k/v: (B, Hkv, Skv, D).  The chunk body is
-    wrapped in jax.checkpoint so backward recomputes the (…, Sq, chunk)
-    logits instead of storing them — O(Sq·chunk) live memory.
+    qg: (B, Hkv, G, Sq, D); k/v: (B, Skv, Hkv, D) → (B, Hkv, G, Sq, D).
+    The chunk body is wrapped in jax.checkpoint so backward recomputes
+    the (…, Sq, chunk) logits instead of storing them — O(Sq·chunk) live
+    memory.
     """
     b, hkv, g, sq, hd = qg.shape
-    skv = k.shape[2]
+    skv = k.shape[1]
     nc = -(-skv // chunk)
     pad = nc * chunk - skv
     if pad:
-        k = jnp.pad(k, ((0, 0), (0, 0), (0, pad), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, 0), (0, pad), (0, 0)))
+        k = jnp.pad(k, ((0, 0), (0, pad), (0, 0), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, pad), (0, 0), (0, 0)))
         kv_positions = jnp.pad(kv_positions, ((0, 0), (0, pad)),
                                constant_values=-1)
 
-    ks = k.reshape(b, hkv, nc, chunk, hd).transpose(2, 0, 1, 3, 4)
-    vs = v.reshape(b, hkv, nc, chunk, hd).transpose(2, 0, 1, 3, 4)
+    ks = k.reshape(b, nc, chunk, hkv, hd).transpose(1, 0, 2, 3, 4)
+    vs = v.reshape(b, nc, chunk, hkv, hd).transpose(1, 0, 2, 3, 4)
     kps = kv_positions.reshape(b, nc, chunk).transpose(1, 0, 2)
     qp = q_positions[:, :, None]
 
@@ -290,7 +294,7 @@ def _chunked_attention(qg, k, v, q_positions, kv_positions, *, causal,
         alpha = jnp.exp(m_prev - m_new)
         l_new = alpha * l_prev + jnp.sum(p, axis=-1, keepdims=True)
         acc = acc * alpha + jnp.einsum(
-            "bngqk,bnkd->bngqd", p, v_blk.astype(jnp.float32))
+            "bngqk,bknd->bngqd", p, v_blk.astype(jnp.float32))
         return (m_new, l_new, acc), None
 
     init = (jnp.full((b, hkv, g, sq, 1), _NEG_INF, jnp.float32),
@@ -316,11 +320,9 @@ def attention(x, p, cfg, positions, *, window: Optional[int] = None,
         out = out.transpose(0, 2, 1, 3)
     else:
         out = _xla_attention(
-            q.transpose(0, 2, 1, 3), k.transpose(0, 2, 1, 3),
-            v.transpose(0, 2, 1, 3), causal=True, window=window,
+            q, k, v, causal=True, window=window,
             softcap=cfg.attn_softcap, scale=scale,
             chunk=getattr(cfg, "attn_chunk", _KV_CHUNK))
-        out = out.transpose(0, 2, 1, 3)
     y = dense(out.reshape(b, s, -1), p["o"], cfg)
     if return_kv:
         return y, (k, v)
@@ -416,13 +418,12 @@ def decode_attention(x, p, cfg, cache, pos, *, window: Optional[int] = None):
         out = out.reshape(b, 1, -1)
     else:
         out = _xla_attention(
-            q.transpose(0, 2, 1, 3), knew.transpose(0, 2, 1, 3),
-            vnew.transpose(0, 2, 1, 3), causal=True, window=window,
+            q, knew, vnew, causal=True, window=window,
             softcap=cfg.attn_softcap, scale=scale,
             kv_positions=kv_positions,
             q_positions=pos_b[:, None],
             chunk=getattr(cfg, "attn_chunk", _KV_CHUNK))
-        out = out.transpose(0, 2, 1, 3).reshape(b, 1, -1)
+        out = out.reshape(b, 1, -1)
     return dense(out, p["o"], cfg), new_cache
 
 
@@ -460,8 +461,67 @@ def init_paged_attn_cache(cfg, num_pages: int, page_size: int, dtype):
             "v_pages": jnp.zeros(shape, fmt.operand_jnp)}
 
 
+def _store_kv(cache, layer, phys, slot, k, v, fmt):
+    """Write K/V (..., kv, hd) at (physical page, slot) of a paged pool,
+    quantized under ``fmt`` when the pool holds scales; with ``slot``
+    None, K/V are whole pages (..., page, kv, hd) written at ``phys``.
+
+    ``layer`` is None for one layer's (num_pages, page, kv, ·) leaves, or
+    the index into the stacked (n_groups, num_pages, page, kv, ·) leaves
+    the layer scan carries: the scatter then updates the stacked pool
+    where it lies, and no layer slab is sliced out or written back.
+
+    A token is written one head at a time: each update's window is the
+    hd axis, which every layout of the pool keeps contiguous.  A TPU
+    keeps a pool of 20 KV heads with its kv axis between the page and
+    slot axes, and a (kv, hd) window there makes XLA relayout the whole
+    pool around the scatter.  A whole page is contiguous in any layout.
+    """
+    if slot is None:
+        at = (phys,)
+    else:
+        heads = jnp.arange(cache["k_pages"].shape[-2])
+        at = (phys[..., None], slot[..., None], heads)
+    at = at if layer is None else (layer,) + at
+    if "k_scale" in cache:
+        kq, ks = _quantize_kv(k, per_channel=fmt.per_channel)
+        vq, vs = _quantize_kv(v, per_channel=fmt.per_channel)
+        vals = {"k_pages": kq, "k_scale": ks, "v_pages": vq, "v_scale": vs}
+    else:
+        vals = {"k_pages": k, "v_pages": v}
+    new_cache = dict(cache)
+    for name, val in vals.items():
+        new_cache[name] = cache[name].at[at].set(val.astype(cache[name].dtype))
+    return new_cache
+
+
+def _gather_kv(cache, layer, table, dtype):
+    """The pages ``table`` (B, n) selects, in logical order, as K/V
+    (B, n·page, kv, hd): position j of row b is absolute position j.
+    Unmapped (−1) entries read the null page.  Quantized pools dequantize
+    to ``dtype``; raw pools keep their stored dtype."""
+    idx = jnp.maximum(table, 0)
+
+    def pages(leaf):
+        g = leaf[idx] if layer is None else leaf[layer, idx]
+        return g.reshape(g.shape[0], -1, *g.shape[3:])
+
+    kg, vg = pages(cache["k_pages"]), pages(cache["v_pages"])
+    if "k_scale" in cache:
+        kg = _dequantize_kv(kg, pages(cache["k_scale"]), dtype)
+        vg = _dequantize_kv(vg, pages(cache["v_scale"]), dtype)
+    return kg, vg
+
+
+def _layer_pool(cache, layer):
+    """One layer's pool leaves (the Pallas kernels take a single layer)."""
+    if layer is None:
+        return cache
+    return {name: leaf[layer] for name, leaf in cache.items()}
+
+
 def paged_decode_attention(x, p, cfg, cache, pos, page_table, *,
-                           window: Optional[int] = None):
+                           window: Optional[int] = None, layer=None):
     """One-token decode over a paged KV pool.
 
     x: (B, 1, D); pos: scalar or (B,) per-sequence positions; page_table:
@@ -472,74 +532,51 @@ def paged_decode_attention(x, p, cfg, cache, pos, page_table, *,
     (table[pos // page], pos % page); attention then reads the
     table-selected pages — via the page-table-indexed flash-decode kernel
     on the pallas backend, or a gather + masked XLA attention otherwise.
+    ``layer`` indexes a stacked pool (see :func:`_store_kv`).
     Returns (out, new_cache).
     """
     b = x.shape[0]
     hd = cfg.hd
     pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
     q, k, v = _project_qkv_decode(x, p, cfg, pos_b[:, None])
-    page = cache["k_pages"].shape[1]
+    page = cache["k_pages"].shape[-3]
     maxp = page_table.shape[1]
     rows = jnp.arange(b)
     # Inactive slots (all-unmapped rows) clamp to the null page 0.
     phys = jnp.maximum(page_table[rows, pos_b // page], 0)
-    slot = pos_b % page
-    fmt = _kv_storage_format(cfg)
-    quant = "k_scale" in cache
-    new_cache = dict(cache)
-    if quant:
-        kq, ks = _quantize_kv(k[:, 0], per_channel=fmt.per_channel)
-        vq, vs = _quantize_kv(v[:, 0], per_channel=fmt.per_channel)
-        new_cache["k_pages"] = cache["k_pages"].at[phys, slot].set(kq)
-        new_cache["k_scale"] = cache["k_scale"].at[phys, slot].set(ks)
-        new_cache["v_pages"] = cache["v_pages"].at[phys, slot].set(vq)
-        new_cache["v_scale"] = cache["v_scale"].at[phys, slot].set(vs)
-    else:
-        dt = cache["k_pages"].dtype
-        new_cache["k_pages"] = cache["k_pages"].at[phys, slot].set(
-            k[:, 0].astype(dt))
-        new_cache["v_pages"] = cache["v_pages"].at[phys, slot].set(
-            v[:, 0].astype(dt))
+    new_cache = _store_kv(cache, layer, phys, pos_b % page, k[:, 0], v[:, 0],
+                          _kv_storage_format(cfg))
 
     seq_lens = pos_b + 1
     scale = cfg.attn_scale if cfg.attn_scale is not None else hd ** -0.5
     if cfg.gemm_backend == "pallas":
         from repro.kernels import ops
+        pool = _layer_pool(new_cache, layer)
         out = ops.flash_decode_paged(
-            q[:, 0], new_cache["k_pages"], new_cache["v_pages"],
+            q[:, 0], pool["k_pages"], pool["v_pages"],
             page_table, seq_lens,
-            k_scale=new_cache.get("k_scale"),
-            v_scale=new_cache.get("v_scale"),
+            k_scale=pool.get("k_scale"), v_scale=pool.get("v_scale"),
             window=window, softcap=cfg.attn_softcap, scale=scale)
         out = out.reshape(b, 1, -1)
     else:
-        # Gather the table-selected pages back into logical order: slot j
-        # of the gathered view is absolute position j, so the masked XLA
+        # The gathered view is in logical order, so the masked XLA
         # attention below is bit-identical to the contiguous-cache path.
-        def gather(leaf):
-            g = leaf[jnp.maximum(page_table, 0)]   # (B, maxp, page, kv, ·)
-            return g.reshape(b, maxp * page, *leaf.shape[2:])
-
-        kg = gather(new_cache["k_pages"])
-        vg = gather(new_cache["v_pages"])
-        if quant:
-            cdt = jnp.dtype(cfg.compute_dtype)
-            kg = _dequantize_kv(kg, gather(new_cache["k_scale"]), cdt)
-            vg = _dequantize_kv(vg, gather(new_cache["v_scale"]), cdt)
+        kg, vg = _gather_kv(new_cache, layer, page_table,
+                            jnp.dtype(cfg.compute_dtype))
         idx = jnp.arange(maxp * page)[None, :]
         mapped = jnp.repeat(page_table >= 0, page, axis=1)
         kv_positions = jnp.where((idx <= pos_b[:, None]) & mapped, idx, -1)
         out = _xla_attention(
-            q.transpose(0, 2, 1, 3), kg.transpose(0, 2, 1, 3),
-            vg.transpose(0, 2, 1, 3), causal=True, window=window,
+            q, kg, vg, causal=True, window=window,
             softcap=cfg.attn_softcap, scale=scale,
             kv_positions=kv_positions, q_positions=pos_b[:, None],
             chunk=getattr(cfg, "attn_chunk", _KV_CHUNK))
-        out = out.transpose(0, 2, 1, 3).reshape(b, 1, -1)
+        out = out.reshape(b, 1, -1)
     return dense(out, p["o"], cfg), new_cache
 
 
-def verify_paged_attention(x, p, cfg, cache, pos, page_table):
+def verify_paged_attention(x, p, cfg, cache, pos, page_table, *,
+                           layer=None):
     """Score a K-token speculative window over the paged KV pool.
 
     x: (B, K, D) — per row, the last emitted token followed by K−1 draft
@@ -561,6 +598,7 @@ def verify_paged_attention(x, p, cfg, cache, pos, page_table):
     point hold garbage the next window simply overwrites — the engine
     rewinds only the host-side position (global-attention pages are
     position-addressed, so no old KV is ever overwritten by the window).
+    ``layer`` indexes a stacked pool (see :func:`_store_kv`).
     Returns (out, new_cache).
     """
     b, klen, _ = x.shape
@@ -568,28 +606,13 @@ def verify_paged_attention(x, p, cfg, cache, pos, page_table):
     pos_b = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
     positions = pos_b[:, None] + jnp.arange(klen, dtype=jnp.int32)[None, :]
     q, k, v = _project_qkv_decode(x, p, cfg, positions)
-    page = cache["k_pages"].shape[1]
+    page = cache["k_pages"].shape[-3]
     maxp = page_table.shape[1]
     rows = jnp.arange(b)[:, None]
     # Inactive slots (all-unmapped rows) clamp to the null page 0.
     phys = jnp.maximum(page_table[rows, positions // page], 0)   # (B, K)
-    slot = positions % page
-    fmt = _kv_storage_format(cfg)
-    quant = "k_scale" in cache
-    new_cache = dict(cache)
-    if quant:
-        kq, ks = _quantize_kv(k, per_channel=fmt.per_channel)
-        vq, vs = _quantize_kv(v, per_channel=fmt.per_channel)
-        new_cache["k_pages"] = cache["k_pages"].at[phys, slot].set(kq)
-        new_cache["k_scale"] = cache["k_scale"].at[phys, slot].set(ks)
-        new_cache["v_pages"] = cache["v_pages"].at[phys, slot].set(vq)
-        new_cache["v_scale"] = cache["v_scale"].at[phys, slot].set(vs)
-    else:
-        dt = cache["k_pages"].dtype
-        new_cache["k_pages"] = cache["k_pages"].at[phys, slot].set(
-            k.astype(dt))
-        new_cache["v_pages"] = cache["v_pages"].at[phys, slot].set(
-            v.astype(dt))
+    new_cache = _store_kv(cache, layer, phys, positions % page, k, v,
+                          _kv_storage_format(cfg))
 
     scale = cfg.attn_scale if cfg.attn_scale is not None else hd ** -0.5
     if cfg.gemm_backend == "pallas":
@@ -598,43 +621,34 @@ def verify_paged_attention(x, p, cfg, cache, pos, page_table):
         # the exact kernel vanilla decode uses — bit-identity by
         # construction.  seq_lens masks each query to its own prefix.
         from repro.kernels import ops
+        pool = _layer_pool(new_cache, layer)
         outs = []
         for i in range(klen):
             o = ops.flash_decode_paged(
-                q[:, i], new_cache["k_pages"], new_cache["v_pages"],
+                q[:, i], pool["k_pages"], pool["v_pages"],
                 page_table, pos_b + i + 1,
-                k_scale=new_cache.get("k_scale"),
-                v_scale=new_cache.get("v_scale"),
+                k_scale=pool.get("k_scale"), v_scale=pool.get("v_scale"),
                 window=None, softcap=cfg.attn_softcap, scale=scale)
             outs.append(o.reshape(b, 1, -1))
         out = jnp.concatenate(outs, axis=1)
     else:
-        def gather(leaf):
-            g = leaf[jnp.maximum(page_table, 0)]   # (B, maxp, page, kv, ·)
-            return g.reshape(b, maxp * page, *leaf.shape[2:])
-
-        kg = gather(new_cache["k_pages"])
-        vg = gather(new_cache["v_pages"])
-        if quant:
-            cdt = jnp.dtype(cfg.compute_dtype)
-            kg = _dequantize_kv(kg, gather(new_cache["k_scale"]), cdt)
-            vg = _dequantize_kv(vg, gather(new_cache["v_scale"]), cdt)
+        kg, vg = _gather_kv(new_cache, layer, page_table,
+                            jnp.dtype(cfg.compute_dtype))
         idx = jnp.arange(maxp * page)[None, :]
         mapped = jnp.repeat(page_table >= 0, page, axis=1)
         kv_positions = jnp.where((idx <= positions[:, -1:]) & mapped,
                                  idx, -1)
         out = _xla_attention(
-            q.transpose(0, 2, 1, 3), kg.transpose(0, 2, 1, 3),
-            vg.transpose(0, 2, 1, 3), causal=True, window=None,
+            q, kg, vg, causal=True, window=None,
             softcap=cfg.attn_softcap, scale=scale,
             kv_positions=kv_positions, q_positions=positions,
             chunk=getattr(cfg, "attn_chunk", _KV_CHUNK))
-        out = out.transpose(0, 2, 1, 3).reshape(b, klen, -1)
+        out = out.reshape(b, klen, -1)
     return dense(out, p["o"], cfg), new_cache
 
 
 def paged_prefill_attention(x, p, cfg, cache, positions, page_table, *,
-                            kv_len: int):
+                            kv_len: int, layer=None):
     """One prefill *chunk* over the paged KV pool.
 
     x: (1, C, D) chunk activations; positions: (1, C) absolute positions
@@ -650,50 +664,38 @@ def paged_prefill_attention(x, p, cfg, cache, positions, page_table, *,
     path re-reads cached KV, it never recomputes it).  ``kv_len`` is
     static, so every chunk index compiles once, the gather touches only
     the live prefix pages, and all chunk GEMMs share the single (C, D)
-    plan-cache signature.  Returns (out, new_cache).
+    plan-cache signature.  ``layer`` indexes a stacked pool (see
+    :func:`_store_kv`).  Returns (out, new_cache).
     """
     b, c_len, _ = x.shape
     hd = cfg.hd
     q, k, v = _project_qkv(x, p, cfg, positions)
-    page = cache["k_pages"].shape[1]
-    pos_v = positions[0]                       # (C,) absolute positions
-    phys = jnp.maximum(page_table[0, pos_v // page], 0)
-    slot = pos_v % page
+    page = cache["k_pages"].shape[-3]
     fmt = _kv_storage_format(cfg)
-    quant = "k_scale" in cache
-    new_cache = dict(cache)
-    if quant:
-        kq, ks = _quantize_kv(k[0], per_channel=fmt.per_channel)
-        vq, vs = _quantize_kv(v[0], per_channel=fmt.per_channel)
-        new_cache["k_pages"] = cache["k_pages"].at[phys, slot].set(kq)
-        new_cache["k_scale"] = cache["k_scale"].at[phys, slot].set(ks)
-        new_cache["v_pages"] = cache["v_pages"].at[phys, slot].set(vq)
-        new_cache["v_scale"] = cache["v_scale"].at[phys, slot].set(vs)
+    pos0 = kv_len - c_len
+    if pos0 % page == 0 and c_len % page == 0:
+        # The chunk fills whole pages: one update per page, not per
+        # (token, head).
+        phys = jnp.maximum(page_table[0, pos0 // page:kv_len // page], 0)
+        new_cache = _store_kv(cache, layer, phys, None,
+                              k[0].reshape(-1, page, *k.shape[2:]),
+                              v[0].reshape(-1, page, *v.shape[2:]), fmt)
     else:
-        dt = cache["k_pages"].dtype
-        new_cache["k_pages"] = cache["k_pages"].at[phys, slot].set(
-            k[0].astype(dt))
-        new_cache["v_pages"] = cache["v_pages"].at[phys, slot].set(
-            v[0].astype(dt))
+        pos_v = positions[0]                   # (C,) absolute positions
+        phys = jnp.maximum(page_table[0, pos_v // page], 0)
+        new_cache = _store_kv(cache, layer, phys, pos_v % page, k[0], v[0],
+                              fmt)
 
     # Gather only the pages holding the prior prefix [0, pos0) into
     # logical order (slot j of the view is absolute position j) and
     # append the chunk's full-precision K/V — pos0 = kv_len − C is
     # static, so the read is bounded by the live prefix, not max_pages.
-    pos0 = kv_len - c_len
     n_prefix = -(-pos0 // page)                    # pages covering [0, pos0)
     cdt = jnp.dtype(cfg.compute_dtype)
 
-    def gather(leaf):
-        g = leaf[jnp.maximum(page_table[:, :n_prefix], 0)]
-        return g.reshape(b, n_prefix * page, *leaf.shape[2:])[:, :pos0]
-
     if pos0:
-        kg = gather(new_cache["k_pages"])
-        vg = gather(new_cache["v_pages"])
-        if quant:
-            kg = _dequantize_kv(kg, gather(new_cache["k_scale"]), cdt)
-            vg = _dequantize_kv(vg, gather(new_cache["v_scale"]), cdt)
+        kg, vg = _gather_kv(new_cache, layer, page_table[:, :n_prefix], cdt)
+        kg, vg = kg[:, :pos0], vg[:, :pos0]
         kg = jnp.concatenate([kg.astype(cdt), k.astype(cdt)], axis=1)
         vg = jnp.concatenate([vg.astype(cdt), v.astype(cdt)], axis=1)
     else:
@@ -708,12 +710,10 @@ def paged_prefill_attention(x, p, cfg, cache, positions, page_table, *,
         out = out.transpose(0, 2, 1, 3)
     else:
         out = _xla_attention(
-            q.transpose(0, 2, 1, 3), kg.transpose(0, 2, 1, 3),
-            vg.transpose(0, 2, 1, 3), causal=True, window=None,
+            q, kg, vg, causal=True, window=None,
             softcap=cfg.attn_softcap, scale=scale,
             q_positions=positions,
             chunk=getattr(cfg, "attn_chunk", _KV_CHUNK))
-        out = out.transpose(0, 2, 1, 3)
     return dense(out.reshape(b, c_len, -1), p["o"], cfg), new_cache
 
 
@@ -746,12 +746,10 @@ def ring_chunk_attention(x, p, cfg, cache, positions, *, pos0: int,
     vc = jnp.concatenate([ring_v, v.astype(ring_v.dtype)], axis=1)
     scale = cfg.attn_scale if cfg.attn_scale is not None else hd ** -0.5
     out = _xla_attention(
-        q.transpose(0, 2, 1, 3), kc.transpose(0, 2, 1, 3),
-        vc.transpose(0, 2, 1, 3), causal=True, window=window,
+        q, kc, vc, causal=True, window=window,
         softcap=cfg.attn_softcap, scale=scale,
         kv_positions=kv_positions, q_positions=positions,
         chunk=getattr(cfg, "attn_chunk", _KV_CHUNK))
-    out = out.transpose(0, 2, 1, 3)
 
     keep = min(c_len, length)
     slots = (pos0 + c_len - keep + np.arange(keep)) % length

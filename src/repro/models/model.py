@@ -126,6 +126,11 @@ def _slot_update(full, one, slot):
             f, o.astype(f.dtype), slot, axis=0), full, one)
 
 
+def _is_paged(cache) -> bool:
+    """A paged KV pool layer cache (as opposed to per-slot state)."""
+    return isinstance(cache, dict) and "k_pages" in cache
+
+
 def _mask_rows(new, old, row_valid):
     """Keep only the valid batch rows of a batch-axis-0 cache update.
 
@@ -144,8 +149,11 @@ def _mask_rows(new, old, row_valid):
 def _apply_layer(x, lp, cfg: ArchConfig, kinds, positions, mode: str,
                  cache=None, pos=None, cache_len: Optional[int] = None,
                  page_table=None, slot=None, chunk_pos0: Optional[int] = None,
-                 row_valid=None):
+                 row_valid=None, layer=None):
     """Returns (x, new_cache, aux).
+
+    ``layer`` (paged layers inside the group scan): ``cache`` is the whole
+    stacked pool and ``layer`` this layer's index into it.
 
     ``mode="prefill_chunk"`` runs one (1, C, D) prompt chunk against the
     serving cache: paged attention layers scatter the chunk's KV into
@@ -174,9 +182,7 @@ def _apply_layer(x, lp, cfg: ArchConfig, kinds, positions, mode: str,
     new_cache = None
 
     h = norm(x, lp["norm1"], cfg.norm_type)
-    if mode == "verify" and not (mixer_kind == "attn"
-                                 and isinstance(cache, dict)
-                                 and "k_pages" in cache):
+    if mode == "verify" and not (mixer_kind == "attn" and _is_paged(cache)):
         # Sequential mixers: one exact decode step per window position.
         step = {"rglru": lambda hi, c, i: rglru_mod.rglru_decode(
                     hi, lp["mixer"], cfg, c),
@@ -195,23 +201,24 @@ def _apply_layer(x, lp, cfg: ArchConfig, kinds, positions, mode: str,
     elif mixer_kind in ("attn", "local"):
         if mode == "verify":
             out, new_cache = attn_mod.verify_paged_attention(
-                h, lp["mixer"], cfg, cache, pos, page_table)
+                h, lp["mixer"], cfg, cache, pos, page_table, layer=layer)
         elif mode == "prefill_chunk":
-            if isinstance(cache, dict) and "k_pages" in cache:
+            if _is_paged(cache):
                 out, new_cache = attn_mod.paged_prefill_attention(
                     h, lp["mixer"], cfg, cache, positions, page_table,
-                    kv_len=chunk_pos0 + h.shape[1])
+                    kv_len=chunk_pos0 + h.shape[1], layer=layer)
             else:  # sliding-window ring: per-slot state
                 one = _slot_slice(cache, slot)
                 out, one = attn_mod.ring_chunk_attention(
                     h, lp["mixer"], cfg, one, positions, pos0=chunk_pos0,
                     window=window)
                 new_cache = _slot_update(cache, one, slot)
-        elif mode == "decode" and isinstance(cache, dict) and "k_pages" in cache:
+        elif mode == "decode" and _is_paged(cache):
             # Paged KV pool (serving): the layer reads/writes through the
             # batch-wide page table instead of a per-slot cache stripe.
             out, new_cache = attn_mod.paged_decode_attention(
-                h, lp["mixer"], cfg, cache, pos, page_table, window=window)
+                h, lp["mixer"], cfg, cache, pos, page_table, window=window,
+                layer=layer)
         elif mode == "decode":
             out, new_cache = attn_mod.decode_attention(
                 h, lp["mixer"], cfg, cache, pos, window=window)
@@ -256,8 +263,7 @@ def _apply_layer(x, lp, cfg: ArchConfig, kinds, positions, mode: str,
         raise ValueError(mixer_kind)
 
     if (mode in ("decode", "verify") and row_valid is not None
-            and new_cache is not None
-            and not (isinstance(cache, dict) and "k_pages" in cache)):
+            and new_cache is not None and not _is_paged(cache)):
         new_cache = _mask_rows(new_cache, cache, row_valid)
 
     if cfg.post_norms:
@@ -308,7 +314,15 @@ def _run_stack(x, params, cfg: ArchConfig, positions, mode: str,
                cache=None, pos=None, cache_len: Optional[int] = None,
                page_table=None, slot=None, chunk_pos0: Optional[int] = None,
                row_valid=None):
-    """Scan the group stack + unrolled tail.  Returns (x, new_cache, aux)."""
+    """Scan the group stack + unrolled tail.  Returns (x, new_cache, aux).
+
+    In the modes that write a cache, the paged KV pool is updated where it
+    lies: its stacked (n_groups, num_pages, page, kv, ·) leaves ride in the
+    scan carry and each layer scatters into and gathers from its own index
+    of them, so no layer slices out its whole slab and writes it back (as
+    ``xs``/``ys`` would).  Per-slot state — rings, recurrent state,
+    contiguous caches — stays in ``xs``/``ys``.
+    """
     n_groups, n_tail = _group_layout(cfg)
     kinds = cfg.layer_kinds
     aux_total = jnp.zeros((), jnp.float32)
@@ -318,36 +332,45 @@ def _run_stack(x, params, cfg: ArchConfig, positions, mode: str,
 
     if n_groups:
         has_cache = mode in cached_modes
+        pools = (tuple(c if _is_paged(c) else None for c in cache["groups"])
+                 if threads_cache else (None,) * cfg.period)
 
         def group_body(carry, xs):
             from repro.distributed.sharding import constrain
-            xc, auxc = carry
+            xc, auxc, pools_c = carry
             # Pin the scan carry (and its saved-for-backward residuals) to
             # batch sharding — inference can drift to weight-style sharding.
             xc = constrain(xc, ("pod", "data"), None, None)
-            gp = xs[0] if has_cache and threads_cache else xs
-            gc = xs[1] if has_cache and threads_cache else None
-            caches_out = []
+            gp, gc, i = xs if threads_cache else (xs, None, None)
+            caches_out, pools_out = [], []
             for j in range(cfg.period):
-                layer_cache = gc[j] if gc is not None else None
+                pool = pools_c[j]
+                layer_cache = (pool if pool is not None
+                               else gc[j] if gc is not None else None)
                 xc, c_new, aux = _apply_layer(
                     xc, _index_tree(gp, j), cfg, kinds[j], positions, mode,
                     cache=layer_cache, pos=pos, cache_len=cache_len,
                     page_table=page_table, slot=slot, chunk_pos0=chunk_pos0,
-                    row_valid=row_valid)
-                caches_out.append(c_new)
+                    row_valid=row_valid, layer=None if pool is None else i)
+                caches_out.append(c_new if pool is None else None)
+                pools_out.append(c_new if pool is not None else None)
                 auxc = auxc + aux
             ys = tuple(caches_out) if has_cache else None
-            return (xc, auxc), ys
+            return (xc, auxc, tuple(pools_out)), ys
 
         body = _remat(group_body, cfg)
         if threads_cache:
-            xs = (params["groups"], cache["groups"])
+            rest = tuple(None if pool is not None else c
+                         for pool, c in zip(pools, cache["groups"]))
+            xs = (params["groups"], rest,
+                  jnp.arange(n_groups, dtype=jnp.int32))
         else:
             xs = params["groups"]
-        (x, aux_total), ys = jax.lax.scan(body, (x, aux_total), xs)
+        (x, aux_total, pools), ys = jax.lax.scan(
+            body, (x, aux_total, pools), xs)
         if mode in cached_modes:
-            new_cache["groups"] = ys
+            new_cache["groups"] = tuple(
+                pool if pool is not None else y for pool, y in zip(pools, ys))
 
     for j in range(n_tail):
         idx = n_groups * cfg.period + j

@@ -27,11 +27,12 @@ the launch frontier by up to one step; the pipeline flushes — every
 in-flight step delivered, host state exact — at eviction, speculation,
 snapshot, deadline-cancellation, sequence-horizon and fault boundaries,
 so the resilience and rewind invariants below are unchanged.  The cache
-argument of the decode program is donated (``donate_argnums``):
-back-to-back decode steps update the paged slabs in place instead of
-copying them.  Greedy outputs are bit-identical with ``async_steps`` on
-or off (same program, same inputs — only delivery timing differs);
-``--no-async`` in ``launch/serve.py`` is the escape hatch.
+argument of the decode and prefill-chunk programs is donated
+(``donate_argnums``): back-to-back steps update the paged pool in place
+instead of copying it.  Greedy outputs are bit-identical with
+``async_steps`` on or off (same program, same inputs — only delivery
+timing differs); ``--no-async`` in ``launch/serve.py`` is the escape
+hatch.
 
 **Chunked prefill**: a prompt is prefilled in fixed-size
 ``prefill_chunk`` chunks (default: the whole ``prefill_len`` window)
@@ -531,7 +532,9 @@ class ServingEngine:
     def _chunk_fn(self, format_policy: Optional[str], chunk_idx: int):
         """The jitted prefill-chunk program for one (format, chunk
         index).  Compiled once per pair, then reused — all chunk indices
-        share the same GEMM shapes, so the plan cache solves them once."""
+        share the same GEMM shapes, so the plan cache solves them once.
+        Its cache argument is donated, as the decode program's is: the
+        chunk writes the pool in place instead of into a second pool."""
         if format_policy == self.cfg.format_policy:
             format_policy = None  # engine default: share its compilation
         per_fmt = self._prefill_fns.setdefault(format_policy, {})
@@ -545,7 +548,7 @@ class ServingEngine:
             def serve_prefill_chunk(p, b, c):
                 return model_lib.prefill_chunk(p, b, c, cfg, pos0=pos0)
 
-            fn = jax.jit(serve_prefill_chunk)
+            fn = jax.jit(serve_prefill_chunk, donate_argnums=(2,))
             per_fmt[chunk_idx] = fn
         return fn
 

@@ -41,6 +41,7 @@ import dataclasses
 import time
 from typing import Callable, Dict, List, Optional, Sequence
 
+import jax
 import numpy as np
 
 __all__ = [
@@ -333,7 +334,12 @@ def serve_with_recovery(make_engine: Callable[[], object],
             state["out"] = eng.run()
         except Exception:
             state["snap"] = eng.snapshot()
-            state["cache"] = eng.cache
+            # The decode and chunk programs take the pool donated: a
+            # failure raised inside one leaves ``eng.cache`` naming the
+            # consumed buffers, and the restart then takes a fresh pool.
+            consumed = any(leaf.is_deleted()
+                           for leaf in jax.tree.leaves(eng.cache))
+            state["cache"] = None if consumed else eng.cache
             raise
 
     supervise(attempt, max_restarts=max_restarts, backoff_s=backoff_s,

@@ -427,10 +427,12 @@ def test_serving_per_request_format(tmp_path):
 
 
 def _prefill_logits(eng, fmt, tokens):
-    """Logits of the engine's first prefill chunk under policy ``fmt``."""
+    """Logits of the engine's first prefill chunk under policy ``fmt``.
+    The chunk program takes the engine's cache donated, so the engine
+    keeps the cache it returns, as ``ServingEngine`` does."""
     batch = eng._make_batch(tokens[None], table=eng.sched.table_row(0)[None],
                             slot=0)
-    logits, _ = eng._chunk_fn(fmt, 0)(eng.params, batch, eng.cache)
+    logits, eng.cache = eng._chunk_fn(fmt, 0)(eng.params, batch, eng.cache)
     return np.asarray(logits, np.float32)
 
 

@@ -74,12 +74,16 @@ def test_chunked_xla_attention_matches_kernel_semantics(monkeypatch):
     import repro.models.attention as A
     q, k, v = _qkv(2, 4, 2, 96, 96, 32)
     want = ref.flash_attention(q, k, v, causal=True, window=24)
-    direct = A._xla_attention(q, k, v, causal=True, window=24, softcap=None,
-                              scale=32 ** -0.5)
+
+    def xla(q, k, v):       # the kernel's (B, H, S, D) in and out
+        t = lambda a: a.transpose(0, 2, 1, 3)
+        return t(A._xla_attention(t(q), t(k), t(v), causal=True, window=24,
+                                  softcap=None, scale=32 ** -0.5))
+
+    direct = xla(q, k, v)
     np.testing.assert_allclose(direct, want, rtol=3e-4, atol=3e-4)
     monkeypatch.setattr(A, "_CHUNK_THRESHOLD", 32)  # force chunked path
-    chunked = A._xla_attention(q, k, v, causal=True, window=24, softcap=None,
-                               scale=32 ** -0.5)
+    chunked = xla(q, k, v)
     np.testing.assert_allclose(chunked, want, rtol=3e-4, atol=3e-4)
 
 

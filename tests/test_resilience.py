@@ -260,6 +260,87 @@ def test_crash_snapshot_restore_completes_everything(setup):
     resumed.sched.pool.audit()
 
 
+def test_recovery_keeps_donated_pool_after_chunk_fault(setup):
+    """The chunk and decode programs take the pool donated.  A chunk
+    fault (raised before the call), then a crash: the restarted engine
+    restores onto the surviving pool, re-attaches its published pages,
+    and every request finishes — the faulted one with its error, the
+    others with the fault-free greedy tokens."""
+    cfg, params, prompts = setup
+    kw = dict(prefill_chunk=8, page_size=8)
+    _, base = _serve(params, cfg, prompts, max_tokens=6, engine_kw=kw)
+    inj = FaultInjector([Fault("chunk_exception", rid=1, chunk=1),
+                         Fault("crash", step=5)])
+    engines = []
+
+    def make_engine():
+        engines.append(_engine(params, cfg, fault=inj, **kw))
+        return engines[-1]
+
+    reqs = [Request(rid=i, prompt=p, max_tokens=6)
+            for i, p in enumerate(prompts)]
+    out = serve_with_recovery(make_engine, reqs, max_restarts=2,
+                              backoff_s=0.0, keep_cache=True,
+                              log=lambda *a: None)
+    assert [k for _, k, _ in inj.fired] == ["chunk_exception", "crash"]
+    assert len(engines) == 2
+    assert engines[1].sched.pool.prefix_hit_pages > 0
+    assert out[1].status == "error"
+    for rid in (0, 2, 3):
+        assert out[rid].status == "ok" and list(out[rid]) == list(base[rid])
+    engines[1].sched.pool.audit()
+
+
+def test_recovery_drops_pool_consumed_by_failed_chunk(setup, monkeypatch):
+    """A failure raised inside the chunk program, after it took the pool
+    donated, leaves the engine naming deleted buffers: recovery restarts
+    on a fresh pool instead of restoring onto them, and every request
+    finishes with the fault-free greedy tokens."""
+    cfg, params, prompts = setup
+    kw = dict(prefill_chunk=8, page_size=8)
+    _, base = _serve(params, cfg, prompts, max_tokens=6, engine_kw=kw)
+    real = ServingEngine._chunk_fn
+    failed, engines = [], []
+
+    def chunk_fn(self, format_policy, chunk_idx):
+        fn = real(self, format_policy, chunk_idx)
+
+        def failing(p, b, c):
+            out = fn(p, b, c)
+            if chunk_idx == 1 and not failed:
+                failed.append(chunk_idx)
+                raise RuntimeError("chunk program failed after dispatch")
+            return out
+        return failing
+
+    monkeypatch.setattr(ServingEngine, "_chunk_fn", chunk_fn)
+    real_restore = ServingEngine.restore
+    restored_with = []
+
+    def restore(self, snap, *, cache=None):
+        restored_with.append(cache)
+        return real_restore(self, snap, cache=cache)
+
+    monkeypatch.setattr(ServingEngine, "restore", restore)
+
+    def make_engine():
+        engines.append(_engine(params, cfg, **kw))
+        return engines[-1]
+
+    reqs = [Request(rid=i, prompt=p, max_tokens=6)
+            for i, p in enumerate(prompts)]
+    out = serve_with_recovery(make_engine, reqs, max_restarts=2,
+                              backoff_s=0.0, keep_cache=True,
+                              log=lambda *a: None)
+    assert failed and len(engines) == 2
+    assert any(leaf.is_deleted()
+               for leaf in jax.tree.leaves(engines[0].cache))
+    assert restored_with == [None]
+    for rid in range(len(prompts)):
+        assert out[rid].status == "ok" and list(out[rid]) == list(base[rid])
+    engines[1].sched.pool.audit()
+
+
 def test_snapshot_restore_reattaches_published_pages(setup):
     """With the device cache carried across the restart, the snapshot's
     page registrations are restored into the fresh pool, so a restored

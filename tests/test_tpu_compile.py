@@ -145,3 +145,74 @@ def test_rglru_scan_compiles(one_chip):
         one_chip, ((1, CHUNK, RGLRU_WIDTH), jnp.float32),
         ((1, CHUNK, RGLRU_WIDTH), jnp.float32))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("cache_len", [CACHE_LEN, 4096])
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_serving_programs_keep_the_pool_in_place(one_chip, program,
+                                                 cache_len):
+    """qwen1.5-4b's decode and prefill-chunk programs at its published
+    widths (two of its 40 layers: every layer runs the same scan body),
+    sized as its benchmark cells serve it, and at a cache of 4096, whose
+    spans are read by the KV-chunked scan: the donated pool is aliased to
+    the output, no operation yields one layer's whole (pages, page, kv,
+    hd) slab, and none copies or transposes the stacked pool.  The TPU
+    keeps a pool of 20 KV heads with its kv axis between the page and
+    slot axes, so a KV write or read that asks for another layout shows
+    here as a whole-pool copy, which no CPU compile shows."""
+    import dataclasses
+    import re
+
+    from repro.configs import get_config
+    from repro.models import model as model_lib
+    from repro.serving.engine import init_serving_params
+
+    cfg = dataclasses.replace(get_config("qwen15_4b"), n_layers=2,
+                              format_policy="bf16")
+    slots, maxp = 16, cache_len // PAGE
+    num_pages = slots * maxp + 1
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = shaped(jax.eval_shape(lambda k: init_serving_params(k, cfg),
+                                   jax.random.PRNGKey(0)))
+    cache = shaped(jax.eval_shape(lambda: model_lib.init_paged_cache(
+        cfg, slots, cache_len, num_pages=num_pages, page_size=PAGE)))
+    rows = slots if program == "decode" else 1
+    batch = shaped({"tokens": jnp.zeros((rows, 1 if rows > 1 else CHUNK),
+                                        jnp.int32),
+                    "page_table": jnp.zeros((rows, maxp), jnp.int32)})
+    if program == "decode":
+        batch["pos"] = shaped(jnp.zeros(slots, jnp.int32))
+        extra = shaped((jax.random.PRNGKey(0), jnp.zeros(slots, jnp.float32),
+                        jnp.zeros(slots, bool)))
+
+        def step(p, b, c, key, temps, active):
+            return model_lib.decode_and_sample(
+                p, b, c, cfg, key=key, temperatures=temps,
+                active_rows=active)
+    else:
+        batch["slot"] = shaped(jnp.int32(0))
+        extra = ()
+
+        def step(p, b, c):
+            return model_lib.prefill_chunk(p, b, c, cfg,
+                                           pos0=cache_len - CHUNK)
+
+    text = jax.jit(step, donate_argnums=(2,)).lower(
+        params, batch, cache, *extra).compile().as_text()
+
+    slab = f"{num_pages},{PAGE},{cfg.n_kv_heads},{cfg.hd}"
+    stacked = f"{cfg.n_layers},{slab}"
+    entry = text[text.index("\nENTRY"):]
+    pool_params = set(re.findall(
+        r"= bf16\[" + stacked + r"\]\{[^}]*\} parameter\((\d+)\)", entry))
+    aliased = set(re.findall(r"\(\s*(\d+),\s*\{\}", text.splitlines()[0]))
+    assert len(pool_params) == 2 and pool_params <= aliased, aliased
+    per_layer = re.findall(r"%(\S+) = bf16\[(?:1,)?" + slab + r"\]", text)
+    assert not per_layer, per_layer
+    moves = re.findall(r"%(\S+) = bf16\[" + stacked +
+                       r"\]\{[^}]*\} (?:copy|copy-start|transpose)\(", text)
+    assert not moves, moves
