@@ -19,8 +19,9 @@ where the widest gap (``logit_gap``) and the plain mean
 (``mean_logit_gap``), both reported beside it, do not.
 
 The control (``control=True``) reads the same gap for the token that the
-reference computed in the precision below the served one (int8 operands
-for bfloat16) puts first at each of those positions: the lower precision
+reference computed in the precision below the served one (the reference
+module's ``CONTROL``: int8 operands for bfloat16) puts first at each of
+those positions: the lower precision
 the comparison has to reject.
 :func:`control_readings` puts those readings in the program's place, so
 the same :func:`verdict` judges them.
@@ -36,7 +37,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from bench.harness import Window
-from bench.reference import decoder
 
 
 @dataclasses.dataclass
@@ -123,7 +123,7 @@ def widest_gap(ref, shape, seed: int, sample: Sample, dims: Tuple[int, int,
     t0 = time.perf_counter()
     picks = [padded(sample.served)]
     if control:
-        picks.append(padded(run(decoder.CONTROL[shape.served],
+        picks.append(padded(run(ref.CONTROL[shape.served],
                                 lambda lg, i: _argmax(lg))))
         readings["control_s"] = time.perf_counter() - t0
     t1 = time.perf_counter()

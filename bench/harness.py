@@ -17,11 +17,9 @@ import time
 from typing import Dict, List, Optional
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 
 from bench import generator
-from bench.reference import decoder
 from bench.spec import Cell
 
 clock = time.perf_counter
@@ -83,39 +81,53 @@ class CompileCounter:
             self.count += 1
 
 
-def make_params(cfg, shape: decoder.Shape, seed: int):
+def make_params(cfg, ref, shape, seed: int):
     """The program's serving parameters, made by the benchmark from the
     seed in one jitted call. The tree's structure and dtypes are the
     program's (its initialisation, traced and never run); every leaf is
-    the reference's weight (:func:`decoder.layer_weights`,
-    :func:`decoder.head_weights`), so the reference makes the same weights
-    again without taking any from the program."""
+    the weight that the cell's reference module ``ref`` makes for it
+    (``ref.layer_weights``, ``ref.head_weights``), so the reference makes
+    the same weights again without taking any from the program.
+
+    A group's leaves are stacked over the scanned groups: position ``j``
+    of group ``g`` is layer ``g * period + j``, and tail layer ``i``
+    follows the last group."""
     from repro.serving.engine import init_serving_params
-    if cfg.period != 1 or not cfg.scan_layers:
-        raise ValueError("the benchmark's weights expect one scanned "
-                         "layer per group")
-    key = decoder.seed_key(seed)
+    key = ref.seed_key(seed)
     like = jax.eval_shape(lambda k: init_serving_params(k, cfg), key)
+    groups = cfg.n_layers // cfg.period if cfg.scan_layers else 0
 
     def build(key):
         small = jax.random.fold_in(key, 0x5EED)
-        layers = jax.vmap(lambda i: decoder.layer_weights(
-            shape, key, small, i))(jnp.arange(shape.n_layers))
-        head = decoder.head_weights(shape, key, small)
+        head = ref.head_weights(shape, key, small)
+        layers = {}
+
+        def layer(part, i):
+            if (part, i) not in layers:
+                at = (range(i, groups * cfg.period, cfg.period)
+                      if part == "groups" else groups * cfg.period + i)
+                layers[part, i] = ref.layer_weights(shape, key, small, at)
+            return layers[part, i]
+
         suffix = {"w": "", "b": "_b", "scale": "", "bias": "_b"}
 
         def leaf(path, x):
             p = tuple(getattr(k, "key", getattr(k, "idx", None))
                       for k in path)
+            v = None
             if p in (("embedding", "table"), ("embedding", "head")):
                 v = head[p[1]]
             elif p[0] == "final_norm":
                 v = head["final" + suffix[p[1]]]
-            elif p[:2] == ("groups", 0) and p[2] in ("mixer", "ffn"):
-                v = layers[p[3] + suffix[p[4]]]
-            elif p[:2] == ("groups", 0) and p[2] in ("norm1", "norm2"):
-                v = layers[p[2] + suffix[p[3]]]
-            else:
+            elif p[0] in ("groups", "tail") and len(p) > 3:
+                if p[2] in ("mixer", "ffn"):
+                    name = p[3] + (suffix[p[4]] if len(p) > 4 else "")
+                elif p[2] in ("norm1", "norm2"):
+                    name = p[2] + suffix[p[3]]
+                else:
+                    name = None
+                v = layer(p[0], p[1]).get(name)
+            if v is None:
                 raise ValueError(f"no benchmark weight for the program's "
                                  f"parameter {jax.tree_util.keystr(path)}")
             if v.shape != x.shape:

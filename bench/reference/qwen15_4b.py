@@ -1,7 +1,8 @@
 """Qwen1.5 block (the Qwen2 architecture of Hugging Face ``transformers``):
 pre-RMSNorm, rotary embeddings, multi-head attention with q/k/v biases and
 no output bias, SwiGLU MLP without biases, untied LM head."""
-from bench.reference.decoder import Shape, sequence_logits  # noqa: F401
+from bench.reference.decoder import (  # noqa: F401
+    CONTROL, Shape, head_weights, layer_weights, seed_key, sequence_logits)
 
 
 def shape(c: dict) -> Shape:

@@ -2,7 +2,8 @@
 ``transformers``): pre-LayerNorm with biases, rotary embeddings, grouped-
 query attention with biases on q/k/v/o and a sliding window on every
 layer, a plain MLP with biases and the tanh form of GELU, untied LM head."""
-from bench.reference.decoder import Shape, sequence_logits  # noqa: F401
+from bench.reference.decoder import (  # noqa: F401
+    CONTROL, Shape, head_weights, layer_weights, seed_key, sequence_logits)
 
 
 def shape(c: dict) -> Shape:

@@ -65,7 +65,7 @@ def execute(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     """Everything of a run after the look for a chip: set-up, window,
     comparison, metrics. Returns the result line as a dict, with the run's
     other records under ``info``."""
-    from bench import check, generator, harness, stats, trace
+    from bench import check, generator, harness, stats, trace, work
     if seed < 0:
         raise ValueError("--seed must be a non-negative whole number")
     ref = cell.module("reference", cell.config["reference"])
@@ -82,7 +82,7 @@ def execute(cell: spec.Cell, seed: int, seconds: float, traced: bool,
             f"cache_len {e['cache_len']}")
 
     counter = harness.CompileCounter()
-    params = harness.make_params(cfg, shape, seed)
+    params = harness.make_params(cfg, ref, shape, seed)
     engine = harness.build_engine(cell, cfg, params, seed)
     harness.warm_up(engine, cell, seed, cfg.vocab)
     trace_dir = None
@@ -156,7 +156,8 @@ def execute(cell: spec.Cell, seed: int, seconds: float, traced: bool,
         "counters": {k: c1[k] - c0[k] for k in (
             "decode_steps", "active_step_sum", "decode_tokens",
             "prefill_tokens", "cached_prefill_tokens", "preemptions",
-            "completed_requests", "cancelled_requests")},
+            "completed_requests", "cancelled_requests") + work.COUNTERS
+            if k in c1},
         "setup_s": setup_s,
         "bytes_in_use_before_reference": freed_to,
     }

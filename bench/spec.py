@@ -105,45 +105,95 @@ def load_cell(name: str, root: Path = REPO) -> Cell:
                 per_layer=metrics("per_layer"), root=root)
 
 
+# Layer types as configuration files name them, and the program's mixers.
+MIXERS = {"full_attention": "attn", "attention": "attn",
+          "sliding_attention": "local", "mamba": "ssd"}
+
+
 def arch_config(cell: Cell):
     """The program's ``ArchConfig`` for the cell's configuration file.
 
     Starts from the program's own registered config of the same
     architecture and sets every size from the file, so the file is the
-    configuration as it is run. The structure the file cannot express in
-    sizes (norm, MLP form, biases, window) must agree with the program's,
-    or the cell is refused.
+    configuration as it is run. Each layer's mixer comes from the file's
+    ``layer_types`` (or, without them, from its sliding window), its
+    feed-forward from its expert count (``num_experts`` or
+    ``num_local_experts``), with the expert sizes set in the program's own
+    ``moe``. A ``program`` entry's execution settings follow; a nested one
+    (``"moe": {...}``) replaces fields of the program's sub-config. The
+    structure the file cannot express in sizes (norm, MLP form, tied
+    embedding, every layer's kind, the experts' sizes) must agree with
+    the program's, or the cell is refused.
     """
     from repro.configs import get_config
     c = cell.config
     prog = c["program"]
     base = get_config(prog["arch"])
+    n = c["num_hidden_layers"]
     window = c.get("sliding_window")
-    if not c.get("use_sliding_window", window is not None):
-        window = None
+    if "layer_types" in c:
+        unknown = set(c["layer_types"]) - set(MIXERS)
+        if unknown or len(c["layer_types"]) != n:
+            raise SpecError(f"{cell.config_name}: layer_types must name "
+                            f"{n} layers of {sorted(MIXERS)}")
+        mixers = [MIXERS[t] for t in c["layer_types"]]
+        if "local" not in mixers:
+            window = None
+    else:
+        if not c.get("use_sliding_window", window is not None):
+            window = None
+        mixers = ["local" if window is not None else "attn"] * n
+    n_experts = c.get("num_experts", c.get("num_local_experts"))
     sizes = dict(
-        n_layers=c["num_hidden_layers"], d_model=c["hidden_size"],
+        n_layers=n, d_model=c["hidden_size"],
         n_heads=c["num_attention_heads"],
         n_kv_heads=c["num_key_value_heads"],
-        head_dim=c["hidden_size"] // c["num_attention_heads"],
+        head_dim=c.get("head_dim")
+        or c["hidden_size"] // c["num_attention_heads"],
         d_ff=c["intermediate_size"], vocab=c["vocab_size"],
         rope_theta=float(c["rope_theta"]))
-    # Execution settings of the program's own (format policy, dtypes).
-    sizes.update({k: v for k, v in prog.items() if k != "arch"})
+    experts = None
+    if n_experts:
+        ref = cell.module("reference", c["reference"])
+        width = c[getattr(ref, "EXPERT_WIDTH", "moe_intermediate_size")]
+        experts = (n_experts, c["num_experts_per_tok"], width)
+        if base.moe is not None:
+            sizes["moe"] = dataclasses.replace(
+                base.moe, n_experts=n_experts, top_k=experts[1],
+                d_ff_expert=width)
+    # Execution settings of the program's own (format policy, dtypes,
+    # capacity).
+    for k, v in prog.items():
+        if k == "arch":
+            continue
+        if isinstance(v, dict):
+            sub = sizes.get(k, getattr(base, k, None))
+            if not dataclasses.is_dataclass(sub):
+                raise SpecError(f"{cell.config_name}: the program's "
+                                f"{prog['arch']} has no {k} to set")
+            v = dataclasses.replace(sub, **v)
+        sizes[k] = v
     if window is not None:
         sizes["window"] = window
     cfg = dataclasses.replace(base, **sizes)
-    mixers = {m for m, _ in cfg.pattern}
     want = {
         "tied_embeddings": bool(c.get("tie_word_embeddings", False)),
-        "mixer": {"local"} if window is not None else {"attn"},
+        "layer_kinds": tuple(
+            (m, "moe" if n_experts else "mlp") for m in mixers),
+        "experts": experts,
         "mlp_type": {"silu": "swiglu",
                      "gelu_pytorch_tanh": "gelu"}[c["hidden_act"]],
         "norm_type": "rmsnorm" if "rms_norm_eps" in c else "layernorm",
     }
-    have = {"tied_embeddings": cfg.tied_embeddings, "mixer": mixers,
+    sparse = any(f == "moe" for _, f in cfg.layer_kinds)
+    have = {"tied_embeddings": cfg.tied_embeddings,
+            "layer_kinds": cfg.layer_kinds,
+            "experts": (cfg.moe.n_experts, cfg.moe.top_k,
+                        cfg.moe.d_ff_expert) if sparse else None,
             "mlp_type": cfg.mlp_type, "norm_type": cfg.norm_type}
     if want != have:
+        diff = {k: (have[k], want[k]) for k in want if want[k] != have[k]}
         raise SpecError(f"{cell.config_name}: the program's {prog['arch']} "
-                        f"is {have}, the configuration file says {want}")
+                        f"and the configuration file differ in "
+                        f"(program, file): {diff}")
     return cfg

@@ -9,14 +9,13 @@ import numpy as np
 from bench import work
 from bench.harness import Window
 from bench.peaks import Peaks
-from bench.reference.decoder import Shape
 from bench.trace import Summary
 
 
 @dataclasses.dataclass
 class Run:
     window: Window
-    shape: Shape
+    shape: object             # the cell's reference module's shape
     peaks: Peaks
     sizing: dict
     setup_s: float
@@ -71,23 +70,37 @@ def token_gaps(run: Run) -> List[float]:
             for t, j in stamps_in(run, w.open, w.close) if j >= 1]
 
 
+def expert_counts(run: Run, traced: bool = False) -> Optional[tuple]:
+    """The growth of the engine's expert counters (:data:`work.COUNTERS`)
+    across the window (or its traced part); None where it reports none."""
+    c = run.window.traced["counters_close"] if traced \
+        else run.window.counters_close
+    if not all(k in c for k in work.COUNTERS):
+        return None
+    return tuple(delta(run, k, traced) for k in work.COUNTERS)
+
+
 def traced_work(run: Run) -> Optional[work.Work]:
     """The weight-bearing matrix products that the traced part of the
     window needed: the decode steps and prefill chunks that ran between
-    its two flushes."""
+    its two flushes, with the expert work the engine's counters report or,
+    without them, what is certain."""
     tr = run.window.traced
     if tr is None:
         return None
     e = run.sizing["engine"]
     pk, bw = run.peaks.bf16_flops, run.peaks.hbm_bw
+    counted = expert_counts(run, traced=True)
     tokens = sum(1 for _, j in stamps_in(run, tr["open"], tr["close"])
                  if j >= 1)
     w = work.decode_steps(run.shape, int(delta(run, "decode_steps", True)),
-                          tokens, pk, bw)
-    one = work.chunk(run.shape, e["prefill_chunk"], pk, bw)
-    chunks = tr["chunks"]
-    return w + work.Work(one.flops * chunks, one.bytes * chunks,
-                         one.least_s * chunks)
+                          tokens, pk, bw, experts=counted is None)
+    one = work.chunk(run.shape, e["prefill_chunk"], pk, bw,
+                     experts=counted is None)
+    w = w + one * tr["chunks"]
+    if counted is not None:
+        w = w + work.routed(run.shape, *counted, pk, bw)
+    return w
 
 
 def useful_flops(run: Run) -> float:
@@ -95,15 +108,20 @@ def useful_flops(run: Run) -> float:
     prompt of every request whose first token came in the window, and
     every output token delivered in it. Output token j >= 1 needs the
     forward of token j - 1 over the prompt and j earlier tokens; every
-    output token needs one head."""
+    output token needs one head. Expert work is the engine's counters'
+    over the window where it reports them, and else what is certain."""
     w, s = run.window, run.shape
+    counted = expert_counts(run)
+    per_token = work.token_flops(s, experts=counted is None)
     total = 0.0
     for t, j in stamps_in(run, w.open, w.close):
         p = min(len(t.gen.prompt), run.sizing["engine"]["prefill_len"])
         total += work.head_flops(s)
         if j == 0:
-            total += (p * work.token_flops(s)
-                      + work.prompt_attention_flops(s, p))
+            total += p * per_token + work.prompt_attention_flops(s, p)
         else:
-            total += work.token_flops(s) + work.attention_flops(s, p + j)
+            total += per_token + work.attention_flops(s, p + j)
+    if counted is not None:
+        total += work.routed(s, *counted, run.peaks.bf16_flops,
+                             run.peaks.hbm_bw).flops
     return total

@@ -1,5 +1,6 @@
 """A configuration, a mix, a metric and a cell added as new files (and
 entries in ``BENCHMARK.json``) are found by name; no registry is edited."""
+import dataclasses
 import json
 import shutil
 
@@ -70,12 +71,66 @@ def test_missing_pieces_are_refused(root):
         spec.load_cell("tiny_qwen.chat", root)
 
 
-def test_a_config_that_disagrees_with_the_program_is_refused(root):
-    cfg = json.loads((root / "bench/configs/tiny_qwen.json").read_text())
+def _act(cfg):
     cfg["hidden_act"] = "gelu_pytorch_tanh"
-    _add(root, "configs", "tiny_qwen.json", cfg)
+
+
+def _last_layer_windowed(cfg):
+    cfg["layer_types"][-3] = "sliding_attention"
+
+
+def _experts_on_a_dense_program(cfg):
+    cfg.update(num_local_experts=4, num_experts_per_tok=2,
+               moe_intermediate_size=64)
+
+
+def _program_holds_other_experts(cfg):
+    cfg["program"]["moe"]["n_experts"] = 8
+
+
+@pytest.mark.parametrize("config,change", [
+    ("tiny_qwen", _act), ("tiny_hybrid", _last_layer_windowed),
+    ("tiny_qwen", _experts_on_a_dense_program),
+    ("tiny_moe", _program_holds_other_experts)])
+def test_a_config_that_disagrees_with_the_program_is_refused(root, config,
+                                                             change):
+    cfg = json.loads((root / f"bench/configs/{config}.json").read_text())
+    change(cfg)
+    _add(root, "configs", f"{config}.json", cfg)
+    cell = {"tiny_qwen": "tiny_qwen.chat", "tiny_hybrid": "tiny_hybrid.batch",
+            "tiny_moe": "tiny_moe.chat"}[config]
     with pytest.raises(spec.SpecError):
-        spec.arch_config(spec.load_cell("tiny_qwen.chat", root))
+        spec.arch_config(spec.load_cell(cell, root))
+
+
+def test_layer_kinds_and_experts_come_from_the_file(root):
+    hybrid = spec.arch_config(spec.load_cell("tiny_hybrid.batch", root))
+    assert hybrid.layer_kinds == ((("local", "moe"),) * 3
+                                  + (("attn", "moe"),)) * 2 \
+        + (("local", "moe"),) * 2
+    assert (hybrid.hd, hybrid.window, hybrid.moe.capacity_factor) == \
+        (32, 24, 2.0)
+    moe = spec.arch_config(spec.load_cell("tiny_moe.chat", root))
+    assert (moe.moe.n_experts, moe.moe.top_k, moe.moe.d_ff_expert) == \
+        (4, 2, 64)
+
+
+# What the program's registered config changes to for the repository's
+# configuration files, recorded before configurations brought their own
+# layers.
+RECORDED = {"qwen15_4b": {"rope_theta": 5000000.0, "format_policy": "bf16"},
+            "starcoder2_7b": {"n_layers": 16}}
+
+
+@pytest.mark.parametrize("name", sorted(RECORDED))
+def test_the_real_configurations_give_the_recorded_program_config(name):
+    from repro.configs import get_config
+    config = json.loads((spec.REPO / f"bench/configs/{name}.json").read_text())
+    cell = spec.Cell(name=name, config_name=name, chips=1, config=config,
+                     traffic={}, sizing={}, end_to_end=[], per_layer=[],
+                     root=spec.REPO)
+    assert spec.arch_config(cell) == dataclasses.replace(
+        get_config(config["program"]["arch"]), **RECORDED[name])
 
 
 def test_the_benchmark_cells_load(tmp_path):
