@@ -31,6 +31,11 @@ class MoEConfig:
     d_ff_expert: int
     capacity_factor: float = 1.25
     router_aux_weight: float = 0.01
+    # Experts held on this device: the first ``held`` ids.  Set, the layer
+    # serves dropless over its share (``moe.apply_moe_held``); None, every
+    # expert is held and serving dispatches by capacity as training does.
+    held: Optional[int] = None
+    d_ff_shared: int = 0          # a shared SwiGLU expert beside the routed
 
 
 @dataclasses.dataclass(frozen=True)
@@ -40,6 +45,7 @@ class SSMConfig:
     expand: int = 2
     conv_width: int = 4
     chunk: int = 256
+    norm_eps: float = 1e-6        # the gated RMSNorm before out_proj
 
 
 @dataclasses.dataclass(frozen=True)
@@ -69,12 +75,19 @@ class ArchConfig:
     mlp_bias: bool = False
     qk_norm: bool = False
     rope_theta: float = 10000.0
+    rope: bool = True                       # False: no position embedding
     attn_softcap: Optional[float] = None
     final_softcap: Optional[float] = None
     attn_scale: Optional[float] = None      # defaults to head_dim ** -0.5
     post_norms: bool = False                # gemma2 post-attn/ffn norms
     tied_embeddings: bool = False
     embed_scale: bool = False               # gemma: x *= sqrt(d_model)
+    norm_eps: Optional[float] = None        # None: each norm's own default
+    # Granite's multipliers: the embedding's output, each residual branch
+    # and (dividing) the logits.
+    embed_multiplier: float = 1.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
     moe: Optional[MoEConfig] = None
     ssm: Optional[SSMConfig] = None
     rglru: Optional[RGLRUConfig] = None
@@ -143,6 +156,8 @@ class ArchConfig:
                 assert self.window is not None, "local attention needs window"
             if ffn == "moe":
                 assert self.moe is not None
+        if self.moe is not None and self.moe.held is not None:
+            assert 0 < self.moe.held <= self.moe.n_experts, self.moe
 
     @property
     def hd(self) -> int:
@@ -189,8 +204,10 @@ class ArchConfig:
                 k = 3 if self.mlp_type in ("swiglu", "geglu") else 2
                 total += k * d * self.d_ff
             elif ffn == "moe":
-                total += d * self.moe.n_experts  # router
-                total += self.moe.n_experts * 3 * d * self.moe.d_ff_expert
+                m = self.moe
+                total += d * m.n_experts  # router
+                total += (m.held or m.n_experts) * 3 * d * m.d_ff_expert
+                total += 3 * d * m.d_ff_shared
         total += d  # final norm
         return total
 
@@ -200,7 +217,8 @@ class ArchConfig:
             return self.n_params()
         full = self.n_params()
         moe_layers = sum(1 for _, f in self.layer_kinds if f == "moe")
-        all_e = moe_layers * self.moe.n_experts * 3 * self.d_model * self.moe.d_ff_expert
+        held = self.moe.held or self.moe.n_experts
+        all_e = moe_layers * held * 3 * self.d_model * self.moe.d_ff_expert
         act_e = moe_layers * self.moe.top_k * 3 * self.d_model * self.moe.d_ff_expert
         return full - all_e + act_e
 
@@ -227,7 +245,9 @@ class ArchConfig:
             # under fully-unbalanced routing, so smoke tests are exact.
             kw["moe"] = dataclasses.replace(
                 self.moe, n_experts=4, top_k=2, d_ff_expert=64,
-                capacity_factor=4.0)
+                capacity_factor=4.0,
+                held=None if self.moe.held is None else 4,
+                d_ff_shared=64 if self.moe.d_ff_shared else 0)
         if self.ssm:
             kw["ssm"] = dataclasses.replace(
                 self.ssm, d_state=16, head_dim=16, chunk=8)

@@ -112,8 +112,12 @@ def layernorm(x, p, eps: float = 1e-5):
     return y.astype(x.dtype)
 
 
-def norm(x, p, kind: str):
-    return layernorm(x, p) if kind == "layernorm" else rmsnorm(x, p)
+def norm(x, p, kind: str, eps: Optional[float] = None):
+    """``kind`` norm of ``x``; ``eps`` None takes the norm's own default."""
+    kw = {} if eps is None else {"eps": eps}
+    if kind == "layernorm":
+        return layernorm(x, p, **kw)
+    return rmsnorm(x, p, **kw)
 
 
 # -- rotary embeddings ---------------------------------------------------------
@@ -246,7 +250,10 @@ def init_embedding(key, cfg):
 
 
 def embed(tokens, p, cfg):
-    x = jnp.take(p["table"], tokens, axis=0).astype(_cdt(cfg))
+    x = jnp.take(p["table"], tokens, axis=0)
+    if cfg.embed_multiplier != 1.0:
+        x = x.astype(jnp.float32) * cfg.embed_multiplier
+    x = x.astype(_cdt(cfg))
     if cfg.embed_scale:
         x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
     return x
@@ -269,6 +276,8 @@ def unembed(x, p, cfg):
         logits = jnp.einsum("...d,dv->...v", x.astype(odt),
                             p["head"].astype(odt),
                             preferred_element_type=jnp.float32)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     if cfg.final_softcap is not None:
         logits = cfg.final_softcap * jnp.tanh(logits / cfg.final_softcap)
     return logits

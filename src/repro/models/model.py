@@ -152,6 +152,12 @@ def _apply_layer(x, lp, cfg: ArchConfig, kinds, positions, mode: str,
                  row_valid=None, layer=None):
     """Returns (x, new_cache, aux).
 
+    ``aux`` float32: 0 for a layer without experts; for an expert layer a
+    (3,) vector, its router load-balance loss, then the counts of a
+    held-share expert layer (:func:`repro.models.moe.apply_moe_held`;
+    exact in float32 below 2**24), which counts the ``row_valid`` rows
+    (all rows without it).
+
     ``layer`` (paged layers inside the group scan): ``cache`` is the whole
     stacked pool and ``layer`` this layer's index into it.
 
@@ -162,7 +168,7 @@ def _apply_layer(x, lp, cfg: ArchConfig, kinds, positions, mode: str,
     layers carry the state of batch row ``slot``.
 
     ``row_valid`` (decode/verify): (B,) bool — batch rows whose cache
-    update should be kept.  Paged-attention layers ignore it (inactive
+    update should be kept and whose expert work is counted.  Paged-attention layers ignore it (inactive
     rows already write into the reserved null page through the all-−1
     page-table row); batch-axis caches (ring/RG-LRU/SSD state) are
     where-merged so invalid rows keep their prior state.
@@ -181,7 +187,7 @@ def _apply_layer(x, lp, cfg: ArchConfig, kinds, positions, mode: str,
     aux = jnp.zeros((), jnp.float32)
     new_cache = None
 
-    h = norm(x, lp["norm1"], cfg.norm_type)
+    h = norm(x, lp["norm1"], cfg.norm_type, cfg.norm_eps)
     if mode == "verify" and not (mixer_kind == "attn" and _is_paged(cache)):
         # Sequential mixers: one exact decode step per window position.
         step = {"rglru": lambda hi, c, i: rglru_mod.rglru_decode(
@@ -267,28 +273,55 @@ def _apply_layer(x, lp, cfg: ArchConfig, kinds, positions, mode: str,
         new_cache = _mask_rows(new_cache, cache, row_valid)
 
     if cfg.post_norms:
-        out = norm(out, lp["post_norm1"], cfg.norm_type)
-    x = x + out
+        out = norm(out, lp["post_norm1"], cfg.norm_type, cfg.norm_eps)
+    x = _residual(x, out, cfg)
 
     if ffn_kind != "none":
-        h = norm(x, lp["norm2"], cfg.norm_type)
+        h = norm(x, lp["norm2"], cfg.norm_type, cfg.norm_eps)
         if ffn_kind == "moe":
-            out, aux = _moe_dispatch(h, lp["ffn"], cfg)
+            out, aux = _moe_dispatch(h, lp["ffn"], cfg, mode, row_valid)
         else:
             out = mlp(h, lp["ffn"], cfg)
         if cfg.post_norms:
-            out = norm(out, lp["post_norm2"], cfg.norm_type)
-        x = x + out
+            out = norm(out, lp["post_norm2"], cfg.norm_type, cfg.norm_eps)
+        x = _residual(x, out, cfg)
     return x, new_cache, aux
 
 
-def _moe_dispatch(h, ffn_params, cfg: ArchConfig):
-    """Route to the configured MoE implementation.
+def _residual(x, branch, cfg: ArchConfig):
+    if cfg.residual_multiplier != 1.0:
+        branch = branch * jnp.asarray(cfg.residual_multiplier, branch.dtype)
+    return x + branch
 
-    ``a2a`` (the beyond-paper §Perf optimization) needs an ambient mesh
-    with a "model" axis and a sequence divisible by it; otherwise fall back
-    to the GSPMD scatter path (also the single-device smoke-test path).
+
+def _moe_dispatch(h, ffn_params, cfg: ArchConfig, mode: str, row_valid):
+    """Route to the configured MoE implementation: → (out, aux), ``aux``
+    as :func:`_apply_layer` returns it.
+
+    Serving a held share (``cfg.moe.held``) takes the dropless
+    ``apply_moe_held`` in every mode but training, whose capacity path
+    needs every expert held.  Otherwise ``a2a`` (the beyond-paper §Perf
+    optimization) needs an ambient mesh with a "model" axis and a sequence
+    divisible by it, and else falls back to the GSPMD scatter path (also
+    the single-device smoke-test path).  A shared expert adds its output
+    to either.
     """
+    m = cfg.moe
+    loss = jnp.zeros((), jnp.float32)
+    counts = jnp.zeros((2,), jnp.int32)
+    if m.held is not None and mode != "train":
+        out, counts = moe_mod.apply_moe_held(h, ffn_params, cfg, row_valid)
+    elif m.held is not None and m.held < m.n_experts:
+        raise ValueError(f"training needs every expert held, not "
+                         f"{m.held} of {m.n_experts}")
+    else:
+        out, loss = _moe_capacity(h, ffn_params, cfg)
+    if m.d_ff_shared:
+        out = out + moe_mod.shared_expert(h, ffn_params, cfg)
+    return out, jnp.concatenate([loss[None], counts.astype(jnp.float32)])
+
+
+def _moe_capacity(h, ffn_params, cfg: ArchConfig):
     if cfg.moe_impl == "a2a":
         am = jax.sharding.get_abstract_mesh()
         if (am is not None and not getattr(am, "empty", True)
@@ -314,7 +347,8 @@ def _run_stack(x, params, cfg: ArchConfig, positions, mode: str,
                cache=None, pos=None, cache_len: Optional[int] = None,
                page_table=None, slot=None, chunk_pos0: Optional[int] = None,
                row_valid=None):
-    """Scan the group stack + unrolled tail.  Returns (x, new_cache, aux).
+    """Scan the group stack + unrolled tail.  Returns (x, new_cache, aux):
+    ``aux`` sums the layers' (:func:`_apply_layer`).
 
     In the modes that write a cache, the paged KV pool is updated where it
     lies: its stacked (n_groups, num_pages, page, kv, ·) leaves ride in the
@@ -325,7 +359,7 @@ def _run_stack(x, params, cfg: ArchConfig, positions, mode: str,
     """
     n_groups, n_tail = _group_layout(cfg)
     kinds = cfg.layer_kinds
-    aux_total = jnp.zeros((), jnp.float32)
+    aux_total = _zero_aux(cfg)
     new_cache = {"groups": None, "tail": []}
     cached_modes = ("prefill", "decode", "prefill_chunk", "verify")
     threads_cache = mode in ("decode", "prefill_chunk", "verify")
@@ -415,12 +449,30 @@ def forward(params, batch, cfg: ArchConfig):
     x, b, s = _inputs_to_x(batch, params, cfg)
     positions = jnp.arange(s, dtype=jnp.int32)[None, :].repeat(b, 0)
     x, _, aux = _run_stack(x, params, cfg, positions, "train")
-    x = norm(x, params["final_norm"], cfg.norm_type)
+    x = norm(x, params["final_norm"], cfg.norm_type, cfg.norm_eps)
     logits = unembed(x, params["embedding"], cfg)
     # Keep the (B, S, V) logits sharded batch×vocab — unconstrained they
     # replicate and 1M tokens × 256k vocab × f32 is petabytes.
     logits = constrain(logits, ("pod", "data"), None, "model")
-    return logits, aux
+    return logits, _aux_loss(aux)
+
+
+def _zero_aux(cfg: ArchConfig):
+    """A stack's zero ``aux`` (:func:`_apply_layer`): (3,) where a layer
+    holds experts, else a scalar."""
+    sparse = any(ffn == "moe" for _, ffn in cfg.layer_kinds)
+    return jnp.zeros((3,) if sparse else (), jnp.float32)
+
+
+def _aux_loss(aux):
+    return aux if aux.ndim == 0 else aux[0]
+
+
+def _counts(aux):
+    """The expert counts of a stack's ``aux`` (:func:`_run_stack`)."""
+    if aux.ndim == 0:
+        return jnp.zeros((2,), jnp.int32)
+    return aux[1:].astype(jnp.int32)
 
 
 def prefill(params, batch, cfg: ArchConfig, cache_len: Optional[int] = None):
@@ -433,12 +485,13 @@ def prefill(params, batch, cfg: ArchConfig, cache_len: Optional[int] = None):
     positions = jnp.arange(s, dtype=jnp.int32)[None, :].repeat(b, 0)
     x, cache, _ = _run_stack(x, params, cfg, positions, "prefill",
                              cache_len=cache_len)
-    x = norm(x, params["final_norm"], cfg.norm_type)
+    x = norm(x, params["final_norm"], cfg.norm_type, cfg.norm_eps)
     logits = unembed(x[:, -1:], params["embedding"], cfg)
     return logits[:, 0], cache
 
 
-def prefill_chunk(params, batch, cache, cfg: ArchConfig, *, pos0: int):
+def prefill_chunk(params, batch, cache, cfg: ArchConfig, *, pos0: int,
+                  with_counts: bool = False):
     """One fixed-size prompt chunk against a *paged* decode cache.
 
     ``batch``: ``tokens`` (1, C) — the chunk, absolute positions
@@ -450,16 +503,19 @@ def prefill_chunk(params, batch, cache, cfg: ArchConfig, *, pos0: int):
     the one (C, D) plan-cache signature, and the attention read covers
     exactly the live prefix.  Returns (last-position logits (1, V),
     new_cache) — the final chunk's logits seed sampling, mid-prompt
-    chunks' logits are discarded.
+    chunks' logits are discarded.  ``with_counts`` appends the chunk's
+    expert counts (:func:`_run_stack`; every row counts).
     """
     x, b, s = _inputs_to_x(batch, params, cfg)
     positions = pos0 + jnp.arange(s, dtype=jnp.int32)[None, :]
-    x, new_cache, _ = _run_stack(x, params, cfg, positions, "prefill_chunk",
-                                 cache=cache, slot=batch.get("slot", 0),
-                                 page_table=batch["page_table"],
-                                 chunk_pos0=pos0)
-    x = norm(x, params["final_norm"], cfg.norm_type)
+    x, new_cache, aux = _run_stack(
+        x, params, cfg, positions, "prefill_chunk", cache=cache,
+        slot=batch.get("slot", 0), page_table=batch["page_table"],
+        chunk_pos0=pos0)
+    x = norm(x, params["final_norm"], cfg.norm_type, cfg.norm_eps)
     logits = unembed(x[:, -1:], params["embedding"], cfg)
+    if with_counts:
+        return logits[:, 0], new_cache, _counts(aux)
     return logits[:, 0], new_cache
 
 
@@ -473,6 +529,13 @@ def decode(params, batch, cache, cfg: ArchConfig):
     read KV through.  ``batch["row_valid"]`` (optional, (B,) bool) marks
     the rows whose batch-axis cache updates should be kept — see
     :func:`_mask_rows`."""
+    logits, new_cache, _ = _decode(params, batch, cache, cfg)
+    return logits, new_cache
+
+
+def _decode(params, batch, cache, cfg: ArchConfig):
+    """:func:`decode` with the step's expert counts of the ``row_valid``
+    rows: → (logits, new_cache, counts)."""
     pos = batch["pos"]
     x, b, s = _inputs_to_x(batch, params, cfg)
     positions = jnp.broadcast_to(
@@ -480,13 +543,13 @@ def decode(params, batch, cache, cfg: ArchConfig):
     row_valid = batch.get("row_valid")
     if row_valid is not None:
         row_valid = jnp.asarray(row_valid, bool).reshape(-1)
-    x, new_cache, _ = _run_stack(x, params, cfg, positions, "decode",
-                                 cache=cache, pos=pos,
-                                 page_table=batch.get("page_table"),
-                                 row_valid=row_valid)
-    x = norm(x, params["final_norm"], cfg.norm_type)
+    x, new_cache, aux = _run_stack(x, params, cfg, positions, "decode",
+                                   cache=cache, pos=pos,
+                                   page_table=batch.get("page_table"),
+                                   row_valid=row_valid)
+    x = norm(x, params["final_norm"], cfg.norm_type, cfg.norm_eps)
     logits = unembed(x, params["embedding"], cfg)
-    return logits[:, 0], new_cache
+    return logits[:, 0], new_cache, _counts(aux)
 
 
 def sample_token(logits, key, temperature):
@@ -516,7 +579,8 @@ def sample_token(logits, key, temperature):
 
 
 def decode_and_sample(params, batch, cache, cfg: ArchConfig, *,
-                      key, temperatures, active_rows):
+                      key, temperatures, active_rows,
+                      with_counts: bool = False):
     """One decode step with sampling fused into the same jitted program.
 
     This is the async-serving entry point: the host never has to fetch
@@ -532,14 +596,20 @@ def decode_and_sample(params, batch, cache, cfg: ArchConfig, *,
     next_tokens (B, 1) int32, new_cache).  The fp32 logits remain an
     output so fault-injection runs can still fetch and poison them
     host-side; greedy rows are the argmax of exactly these values, so
-    host-side ``np.argmax`` re-derivation matches bit-for-bit."""
-    logits, new_cache = decode(params, batch, cache, cfg)
+    host-side ``np.argmax`` re-derivation matches bit-for-bit.
+    ``with_counts`` appends the step's expert counts of the
+    ``batch["row_valid"]`` rows."""
+    if with_counts:
+        logits, new_cache, counts = _decode(params, batch, cache, cfg)
+    else:
+        logits, new_cache = decode(params, batch, cache, cfg)
     lf = jnp.asarray(logits, jnp.float32)
     tokens, finite = sample_token(lf, key, temperatures)
     active = jnp.asarray(active_rows, bool).reshape(-1)
     next_tokens = jnp.where(active[:, None], tokens[:, None],
                             jnp.asarray(batch["tokens"], jnp.int32))
-    return tokens, finite, lf, next_tokens, new_cache
+    out = (tokens, finite, lf, next_tokens, new_cache)
+    return out + (counts,) if with_counts else out
 
 
 def verify_chunk(params, batch, cache, cfg: ArchConfig):
@@ -573,7 +643,7 @@ def verify_chunk(params, batch, cache, cfg: ArchConfig):
                                  cache=cache, pos=pos_b,
                                  page_table=batch.get("page_table"),
                                  row_valid=row_valid)
-    x = norm(x, params["final_norm"], cfg.norm_type)
+    x = norm(x, params["final_norm"], cfg.norm_type, cfg.norm_eps)
     logits = unembed(x, params["embedding"], cfg)
     return logits.astype(jnp.float32), new_cache
 

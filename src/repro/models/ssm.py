@@ -61,7 +61,7 @@ def _causal_conv(x, w, b):
     return out + b
 
 
-def _gated_rmsnorm(y, z, scale, eps: float = 1e-6):
+def _gated_rmsnorm(y, z, scale, eps: float):
     y = y * jax.nn.silu(z.astype(jnp.float32)).astype(y.dtype)
     yf = y.astype(jnp.float32)
     var = jnp.mean(yf * yf, axis=-1, keepdims=True)
@@ -146,6 +146,7 @@ def _ssd_chunked(x, dt, a_log, bmat, cmat, chunk: int, h0=None):
     return y[:, :s], final_state
 
 
+@jax.named_scope("ssd")
 def ssd_forward(x, p, cfg, *, return_cache: bool = False, cache=None):
     """Full Mamba2 block forward.  x: (B, S, D) → (B, S, D).
 
@@ -177,7 +178,8 @@ def ssd_forward(x, p, cfg, *, return_cache: bool = False, cache=None):
                                   h0=cache["state"] if cache else None)
     y = y + p["D"].astype(jnp.float32)[:, None] * xh
     y = y.reshape(*x.shape[:2], d_inner)
-    y = _gated_rmsnorm(y.astype(cdt), z.astype(cdt), p["norm_scale"])
+    y = _gated_rmsnorm(y.astype(cdt), z.astype(cdt), p["norm_scale"],
+                       s.norm_eps)
     out = jnp.einsum("bsf,fd->bsd", y.astype(cdt),
                      p["out_proj"]["w"].astype(cdt),
                      preferred_element_type=jnp.float32).astype(x.dtype)
@@ -209,6 +211,7 @@ def init_ssd_cache(cfg, batch: int, dtype):
     }
 
 
+@jax.named_scope("ssd")
 def ssd_decode(x, p, cfg, cache) -> Tuple[jax.Array, dict]:
     """One-token recurrent step.  x: (B, 1, D)."""
     s = cfg.ssm
@@ -239,7 +242,8 @@ def ssd_decode(x, p, cfg, cache) -> Tuple[jax.Array, dict]:
     y = jnp.einsum("bhpn,bn->bhp", state, cmat)
     y = y + p["D"].astype(jnp.float32)[:, None] * xh
     y = y.reshape(-1, 1, d_inner)
-    y = _gated_rmsnorm(y.astype(cdt), z[:, None].astype(cdt), p["norm_scale"])
+    y = _gated_rmsnorm(y.astype(cdt), z[:, None].astype(cdt), p["norm_scale"],
+                       s.norm_eps)
     out = jnp.einsum("bsf,fd->bsd", y.astype(cdt),
                      p["out_proj"]["w"].astype(cdt),
                      preferred_element_type=jnp.float32).astype(x.dtype)

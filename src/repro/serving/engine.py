@@ -138,18 +138,20 @@ __all__ = ["Request", "ServingEngine", "init_serving_params",
 
 
 def serving_params(params, *readers: ArchConfig):
-    """``params`` with the GEMM weights (``w`` leaves) and the embedding
-    table / LM head narrowed to the operand width every reader rounds
-    them to.
+    """``params`` with the GEMM weights (``w`` leaves), the expert stacks
+    (``gate``, ``up``, ``down`` leaves of shape ``(E, ., .)``), the expert
+    router and the embedding table / LM head narrowed to the operand width
+    every reader rounds them to.
 
     ``readers`` are the configs whose format policies will compute with
     these parameters: the engine's, a weight-shared draft's, and any
     per-request prefill policy.  The cast applies only when it is exact
     for all of them: each is unquantized with an operand dtype equal to
     its compute dtype, and they agree on it.  Each consumer (the GEMM's
-    operand cast, ``embed``'s cast to the compute dtype, ``unembed``'s
-    cast to the operand dtype) then rounds these leaves to that dtype
-    first, so holding them narrow changes no result.  If any reader
+    operand cast, the grouped expert products' and the router's operand
+    casts, ``embed``'s cast to the compute dtype, ``unembed``'s cast to
+    the operand dtype) then rounds these leaves to that dtype first, so
+    holding them narrow changes no result.  If any reader
     would see more bits (fp32) or quantizes from the weights (int8), the
     tree is returned unchanged.  Norms, biases and every other leaf keep
     their dtype.
@@ -168,7 +170,8 @@ def serving_params(params, *readers: ArchConfig):
 
     def hold(path, x):
         name = getattr(path[-1], "key", None)
-        if (name in ("w", "table", "head")
+        stack = name in ("gate", "up", "down") and x.ndim >= 3
+        if ((name in ("w", "table", "head", "router") or stack)
                 and jnp.issubdtype(x.dtype, jnp.floating)
                 and x.dtype != narrow):
             return x.astype(narrow)
@@ -345,6 +348,15 @@ class ServingEngine:
         # chunk state must be restored after each decode step.
         self._stateful_rows = any(kind[0] != "attn"
                                   for kind in cfg.layer_kinds)
+        # Held-share expert layers count their work in every decode and
+        # chunk program (``moe_assignments``, ``moe_experts_read``): each
+        # program's counts ride in the next delivered entry and are added
+        # up at that entry's one host sync.
+        self._count_experts = (
+            cfg.moe is not None and cfg.moe.held is not None
+            and any(kind[1] == "moe" for kind in cfg.layer_kinds))
+        self._expert_counts = np.zeros(2, np.int64)
+        self._counts_pending: List[jax.Array] = []
         # slot -> in-flight chunked-prefill state
         # {"tokens": (prefill_len,) window, "chunk": next chunk index,
         #  "hashes": the window's page-prefix hashes (None: prefix off)}
@@ -377,7 +389,7 @@ class ServingEngine:
         def serve_decode(p, b, c, key, temps, active):
             return model_lib.decode_and_sample(
                 p, b, c, self.cfg, key=key, temperatures=temps,
-                active_rows=active)
+                active_rows=active, with_counts=self._count_experts)
 
         def serve_scatter_token(lt, tok, slot):
             return lt.at[slot, 0].set(tok)
@@ -546,7 +558,9 @@ class ServingEngine:
             pos0 = chunk_idx * self.prefill_chunk
 
             def serve_prefill_chunk(p, b, c):
-                return model_lib.prefill_chunk(p, b, c, cfg, pos0=pos0)
+                return model_lib.prefill_chunk(
+                    p, b, c, cfg, pos0=pos0,
+                    with_counts=self._count_experts)
 
             fn = jax.jit(serve_prefill_chunk, donate_argnums=(2,))
             per_fmt[chunk_idx] = fn
@@ -671,6 +685,9 @@ class ServingEngine:
                  cow_copies=pool.cow_copies,
                  spec_on=int(self._spec_on),
                  spec_k=self.spec_k)
+        if self._count_experts:
+            m.update(moe_assignments=int(self._expert_counts[0]),
+                     moe_experts_read=int(self._expert_counts[1]))
         if self.spec_k_hist:
             steps = sum(self.spec_k_hist.values())
             m["spec_k_mean"] = (sum(k * n for k, n
@@ -940,13 +957,15 @@ class ServingEngine:
                 key = self._zero_key   # all-greedy: key stream untouched
             # Row-valid mask: ring/recurrent cache rows of slots that are
             # not decoding this step keep their prior state inside the
-            # decode program itself.  Always passed for stateful archs so
-            # the jit signature is stable.  ``slot_pos`` is copied: the
+            # decode program itself, and held-share expert layers count
+            # only these rows.  Always passed for such archs so the jit
+            # signature is stable.  ``slot_pos`` is copied: the
             # device array may alias the host buffer until the launch has
             # read it, and the positions advance in place just below.
             batch = self._make_batch(
                 self._last_tok, pos=self.slot_pos.copy(), table=table,
-                row_valid=active if self._stateful_rows else None)
+                row_valid=(active if self._stateful_rows
+                           or self._count_experts else None))
             # The decode span stays open until delivery: it covers the
             # device-resident window, so async traces show decode visibly
             # overlapping the NEXT step's admit/prefill/sample host spans.
@@ -958,10 +977,10 @@ class ServingEngine:
                 span = tr.span("decode", annotate=False, args={
                     "step": self.step_idx, "rows": len(decoding)})
                 span.__enter__()
-            (tok, finite, logits, self._last_tok,
-             self.cache) = self._decode_step(
+            out = self._decode_step(
                 self.params, batch, self.cache, key, jnp.asarray(temps),
                 jnp.asarray(active))
+            tok, finite, logits, self._last_tok, self.cache = out[:5]
             self._inflight.append({
                 "kind": "decode", "step": self.step_idx, "span": span,
                 "slots": list(decoding),
@@ -969,6 +988,7 @@ class ServingEngine:
                 "pos_after": {s: int(self.slot_pos[s]) + 1
                               for s in decoding},
                 "tok": tok, "finite": finite, "logits": logits,
+                "counts": self._take_counts(out[5:]),
             })
             for slot in decoding:
                 self.slot_pos[slot] += 1
@@ -1034,6 +1054,23 @@ class ServingEngine:
             if span is not None:
                 span.__exit__(None, None, None)
 
+    def _take_counts(self, counts) -> List[jax.Array]:
+        """The expert counts of the programs launched since the last
+        entry, ``counts`` (this program's, if any) last."""
+        taken = self._counts_pending + list(counts)
+        self._counts_pending = []
+        return taken
+
+    def _fetch(self, entry):
+        """The entry's sampled tokens and finite flags, fetched with the
+        expert counts it carries in one host sync."""
+        with tracing.current().span("device_wait"):
+            tok, finite, counts = jax.device_get(
+                (entry["tok"], entry["finite"], entry["counts"]))
+        for c in counts:
+            self._expert_counts += np.asarray(c, np.int64)
+        return tok, finite
+
     def _deliver_decode(self, entry):
         """Host bookkeeping for one delivered decode step: append the
         token, latency notes, finish / horizon checks, quarantine —
@@ -1042,8 +1079,7 @@ class ServingEngine:
         discarded: their device-side write went to pages that are either
         still owned or fully rewritten by a later owner's prefill
         (launch order == device execution order)."""
-        with tracing.current().span("device_wait"):
-            tok, finite = jax.device_get((entry["tok"], entry["finite"]))
+        tok, finite = self._fetch(entry)
         tok = np.asarray(tok).copy()
         finite = np.asarray(finite).copy()
         if self.fault is not None:
@@ -1100,8 +1136,7 @@ class ServingEngine:
         sample): the first token of a freshly prefilled request."""
         slot = entry["slots"][0]
         req = entry["reqs"][slot]
-        with tracing.current().span("device_wait"):
-            tok, finite = jax.device_get((entry["tok"], entry["finite"]))
+        tok, finite = self._fetch(entry)
         if req.done or self.slot_req[slot] is not req:
             return
         if self.quarantine and not bool(np.asarray(finite).reshape(-1)[0]):
@@ -1151,8 +1186,10 @@ class ServingEngine:
             self.fault.chunk_fault(self.step_idx, req.rid, c)
         # A compile or lowering error here is a defect of the program,
         # not of this request: it propagates instead of being contained.
-        logits, self.cache = self._chunk_fn(req.format_policy, c)(
+        out = self._chunk_fn(req.format_policy, c)(
             self.params, batch, self.cache)
+        logits, self.cache = out[:2]
+        self._counts_pending.extend(out[2:])
         # Publish the chunk's fully-written pages to the prefix cache —
         # only now: an eviction mid-prefill must never leave a
         # half-written page findable.
@@ -1200,6 +1237,7 @@ class ServingEngine:
                 "kind": "seed", "step": self.step_idx, "span": None,
                 "slots": [slot], "reqs": {slot: req},
                 "tok": tok, "finite": finite,
+                "counts": self._take_counts(()),
             })
             self.steps_in_flight_max = max(self.steps_in_flight_max,
                                            self.steps_in_flight)

@@ -216,3 +216,63 @@ def test_serving_programs_keep_the_pool_in_place(one_chip, program,
     moves = re.findall(r"%(\S+) = bf16\[" + stacked +
                        r"\]\{[^}]*\} (?:copy|copy-start|transpose)\(", text)
     assert not moves, moves
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_granite_serving_programs_fit_one_chip(one_chip, program):
+    """granite-4.0-h-small's decode and last prefill-chunk programs as its
+    benchmark cell serves them: 20 layers at published widths, 9 of 72
+    experts held, 32 slots of 1280 positions, chunks of 256.  The weights,
+    the pool and the per-slot Mamba-2 state (8.84e9 + 2.79e9 bytes) and
+    the program's temporaries fit one v5e's 16 GiB, the donated cache is
+    aliased to the output, and the expert counts are outputs."""
+    import dataclasses
+
+    from repro.configs import get_config
+    from repro.models import model as model_lib
+    from repro.serving.engine import init_serving_params
+
+    base = get_config("granite4_h_small")
+    cfg = dataclasses.replace(base, n_layers=20, moe=dataclasses.replace(
+        base.moe, held=9))
+    slots, cache_len, chunk = 32, 1280, 256
+    maxp = cache_len // PAGE
+
+    def shaped(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    params = shaped(jax.eval_shape(lambda k: init_serving_params(k, cfg),
+                                   jax.random.PRNGKey(0)))
+    cache = shaped(jax.eval_shape(lambda: model_lib.init_paged_cache(
+        cfg, slots, cache_len, num_pages=slots * maxp + 1, page_size=PAGE)))
+    if program == "decode":
+        batch = shaped({"tokens": jnp.zeros((slots, 1), jnp.int32),
+                        "page_table": jnp.zeros((slots, maxp), jnp.int32),
+                        "pos": jnp.zeros(slots, jnp.int32),
+                        "row_valid": jnp.zeros(slots, bool)})
+        extra = shaped((jax.random.PRNGKey(0), jnp.zeros(slots, jnp.float32),
+                        jnp.zeros(slots, bool)))
+
+        def step(p, b, c, key, temps, active):
+            return model_lib.decode_and_sample(
+                p, b, c, cfg, key=key, temperatures=temps,
+                active_rows=active, with_counts=True)
+    else:
+        batch = shaped({"tokens": jnp.zeros((1, chunk), jnp.int32),
+                        "page_table": jnp.zeros((1, maxp), jnp.int32),
+                        "slot": jnp.int32(0)})
+        extra = ()
+
+        def step(p, b, c):
+            return model_lib.prefill_chunk(p, b, c, cfg,
+                                           pos0=cache_len - 2 * chunk,
+                                           with_counts=True)
+
+    lowered = jax.jit(step, donate_argnums=(2,)).lower(params, batch, cache,
+                                                       *extra)
+    assert lowered.out_info[-1].shape == (2,)
+    mem = lowered.compile().memory_analysis()
+    held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
+    assert held < 0.9 * 2 ** 34, held
+    assert mem.alias_size_in_bytes >= 2.79e9
