@@ -14,7 +14,8 @@ Four entry points:
   decode cache (the serving engine's incremental prefill: attention
   layers write the chunk's KV into pool pages and attend over the pages
   already holding the prefix — including pages merely aliased from the
-  prefix cache — while ring/recurrent layers carry their slot state)
+  prefix cache — while ring/recurrent layers advance their slot's state
+  where it lies in the cache)
 - ``decode``   — single-token cached step
 - ``verify_chunk`` — speculative-decoding verification: K candidate
   tokens per slot scored in one batched pass whose GEMMs carry M = B·K
@@ -113,17 +114,40 @@ def param_count(params) -> int:
 # -- one layer ----------------------------------------------------------------
 
 
-def _slot_slice(tree, slot):
-    """One slot's (1, ...) view of a batch-axis-0 cache tree."""
-    return jax.tree.map(
-        lambda a: jax.lax.dynamic_slice_in_dim(a, slot, 1, axis=0), tree)
+def _layer_state(cache, layer, slot, fn, row_valid=None):
+    """Run ``fn`` on per-slot state and write its new state back where
+    the state lies: → (out, new_cache).
 
+    ``cache`` is batch-axis-0 state (a ring, RG-LRU or SSD state, a
+    contiguous KV cache): one layer's (B, ...) leaves, or with ``layer``
+    the stacked (n_groups, B, ...) leaves the layer scan carries, of
+    which this layer reads and writes only index ``layer``.  ``slot``
+    narrows the read and the write to that batch row, so ``fn`` sees
+    (1, ...) state.  ``fn(state)`` → (out, new_state).  ``row_valid``
+    keeps the old state of invalid rows (:func:`_mask_rows`).
+    """
+    lead = (() if layer is None else (layer,)) + (() if slot is None
+                                                  else (slot,))
 
-def _slot_update(full, one, slot):
-    """Write a (1, ...) slot state back into the batch-axis-0 tree."""
-    return jax.tree.map(
-        lambda f, o: jax.lax.dynamic_update_slice_in_dim(
-            f, o.astype(f.dtype), slot, axis=0), full, one)
+    def at(a):
+        return (lead + (0,) * (a.ndim - len(lead)),
+                (1,) * len(lead) + a.shape[len(lead):])
+
+    def read(a):
+        start, size = at(a)
+        one = jax.lax.dynamic_slice(a, start, size)
+        return one if layer is None else one[0]
+
+    def write(a, new):
+        new = new.astype(a.dtype)
+        return jax.lax.dynamic_update_slice(
+            a, new if layer is None else new[None], at(a)[0])
+
+    old = jax.tree.map(read, cache)
+    out, new = fn(old)
+    if row_valid is not None:
+        new = _mask_rows(new, old, row_valid)
+    return out, jax.tree.map(write, cache, new)
 
 
 def _is_paged(cache) -> bool:
@@ -158,8 +182,10 @@ def _apply_layer(x, lp, cfg: ArchConfig, kinds, positions, mode: str,
     exact in float32 below 2**24), which counts the ``row_valid`` rows
     (all rows without it).
 
-    ``layer`` (paged layers inside the group scan): ``cache`` is the whole
-    stacked pool and ``layer`` this layer's index into it.
+    ``layer`` (inside the group scan of a mode that threads a cache):
+    ``cache`` is the group's whole stacked leaf — paged pool or per-slot
+    state — and ``layer`` this layer's index into it; the layer reads and
+    writes only its own index (:func:`_layer_state`, ``_store_kv``).
 
     ``mode="prefill_chunk"`` runs one (1, C, D) prompt chunk against the
     serving cache: paged attention layers scatter the chunk's KV into
@@ -186,10 +212,22 @@ def _apply_layer(x, lp, cfg: ArchConfig, kinds, positions, mode: str,
     window = cfg.window if mixer_kind == "local" else None
     aux = jnp.zeros((), jnp.float32)
     new_cache = None
-
     h = norm(x, lp["norm1"], cfg.norm_type, cfg.norm_eps)
-    if mode == "verify" and not (mixer_kind == "attn" and _is_paged(cache)):
-        # Sequential mixers: one exact decode step per window position.
+
+    def on_state(c):
+        """(out, new state) of a mixer that carries per-slot state ``c``."""
+        if mode == "prefill_chunk":
+            if mixer_kind in ("attn", "local"):  # sliding-window ring
+                return attn_mod.ring_chunk_attention(
+                    h, lp["mixer"], cfg, c, positions, pos0=chunk_pos0,
+                    window=window)
+            # Chunk 0 starts fresh (the slot row holds its previous
+            # occupant's state); later chunks resume the carried state.
+            forward = {"rglru": rglru_mod.rglru_forward,
+                       "ssd": ssm_mod.ssd_forward}[mixer_kind]
+            return forward(h, lp["mixer"], cfg, return_cache=True,
+                           cache=c if chunk_pos0 else None)
+        # decode, verify: one exact decode step per window position.
         step = {"rglru": lambda hi, c, i: rglru_mod.rglru_decode(
                     hi, lp["mixer"], cfg, c),
                 "ssd": lambda hi, c, i: ssm_mod.ssd_decode(
@@ -199,36 +237,31 @@ def _apply_layer(x, lp, cfg: ArchConfig, kinds, positions, mode: str,
                 "local": lambda hi, c, i: attn_mod.decode_attention(
                     hi, lp["mixer"], cfg, c, pos + i, window=window),
                 }[mixer_kind]
-        outs, new_cache = [], cache
+        outs = []
         for i in range(h.shape[1]):
-            o, new_cache = step(h[:, i:i + 1], new_cache, i)
+            o, c = step(h[:, i:i + 1], c, i)
             outs.append(o)
-        out = jnp.concatenate(outs, axis=1)
+        return jnp.concatenate(outs, axis=1), c
+
+    if mode in ("decode", "prefill_chunk", "verify") and not _is_paged(cache):
+        out, new_cache = _layer_state(
+            cache, layer, slot if mode == "prefill_chunk" else None,
+            on_state, row_valid)
+    elif mode == "verify":
+        out, new_cache = attn_mod.verify_paged_attention(
+            h, lp["mixer"], cfg, cache, pos, page_table, layer=layer)
+    elif mode == "prefill_chunk":
+        out, new_cache = attn_mod.paged_prefill_attention(
+            h, lp["mixer"], cfg, cache, positions, page_table,
+            kv_len=chunk_pos0 + h.shape[1], layer=layer)
+    elif mode == "decode":
+        # Paged KV pool (serving): the layer reads/writes through the
+        # batch-wide page table instead of a per-slot cache stripe.
+        out, new_cache = attn_mod.paged_decode_attention(
+            h, lp["mixer"], cfg, cache, pos, page_table, window=window,
+            layer=layer)
     elif mixer_kind in ("attn", "local"):
-        if mode == "verify":
-            out, new_cache = attn_mod.verify_paged_attention(
-                h, lp["mixer"], cfg, cache, pos, page_table, layer=layer)
-        elif mode == "prefill_chunk":
-            if _is_paged(cache):
-                out, new_cache = attn_mod.paged_prefill_attention(
-                    h, lp["mixer"], cfg, cache, positions, page_table,
-                    kv_len=chunk_pos0 + h.shape[1], layer=layer)
-            else:  # sliding-window ring: per-slot state
-                one = _slot_slice(cache, slot)
-                out, one = attn_mod.ring_chunk_attention(
-                    h, lp["mixer"], cfg, one, positions, pos0=chunk_pos0,
-                    window=window)
-                new_cache = _slot_update(cache, one, slot)
-        elif mode == "decode" and _is_paged(cache):
-            # Paged KV pool (serving): the layer reads/writes through the
-            # batch-wide page table instead of a per-slot cache stripe.
-            out, new_cache = attn_mod.paged_decode_attention(
-                h, lp["mixer"], cfg, cache, pos, page_table, window=window,
-                layer=layer)
-        elif mode == "decode":
-            out, new_cache = attn_mod.decode_attention(
-                h, lp["mixer"], cfg, cache, pos, window=window)
-        elif mode == "prefill":
+        if mode == "prefill":
             out, (k, v) = attn_mod.attention(
                 h, lp["mixer"], cfg, positions, window=window, return_kv=True)
             new_cache = attn_mod.prefill_cache(
@@ -237,40 +270,15 @@ def _apply_layer(x, lp, cfg: ArchConfig, kinds, positions, mode: str,
         else:
             out = attn_mod.attention(h, lp["mixer"], cfg, positions,
                                      window=window)
-    elif mixer_kind == "rglru":
-        if mode == "decode":
-            out, new_cache = rglru_mod.rglru_decode(h, lp["mixer"], cfg, cache)
-        elif mode == "prefill_chunk":
-            # Chunk 0 starts fresh (the slot row holds its previous
-            # occupant's state); later chunks resume the carried state.
-            one = _slot_slice(cache, slot) if chunk_pos0 else None
-            out, one = rglru_mod.rglru_forward(h, lp["mixer"], cfg,
-                                               return_cache=True, cache=one)
-            new_cache = _slot_update(cache, one, slot)
-        elif mode == "prefill":
-            out, new_cache = rglru_mod.rglru_forward(h, lp["mixer"], cfg,
-                                                     return_cache=True)
+    elif mixer_kind in ("rglru", "ssd"):
+        forward = {"rglru": rglru_mod.rglru_forward,
+                   "ssd": ssm_mod.ssd_forward}[mixer_kind]
+        if mode == "prefill":
+            out, new_cache = forward(h, lp["mixer"], cfg, return_cache=True)
         else:
-            out = rglru_mod.rglru_forward(h, lp["mixer"], cfg)
-    elif mixer_kind == "ssd":
-        if mode == "decode":
-            out, new_cache = ssm_mod.ssd_decode(h, lp["mixer"], cfg, cache)
-        elif mode == "prefill_chunk":
-            one = _slot_slice(cache, slot) if chunk_pos0 else None
-            out, one = ssm_mod.ssd_forward(h, lp["mixer"], cfg,
-                                           return_cache=True, cache=one)
-            new_cache = _slot_update(cache, one, slot)
-        elif mode == "prefill":
-            out, new_cache = ssm_mod.ssd_forward(h, lp["mixer"], cfg,
-                                                 return_cache=True)
-        else:
-            out = ssm_mod.ssd_forward(h, lp["mixer"], cfg)
+            out = forward(h, lp["mixer"], cfg)
     else:
         raise ValueError(mixer_kind)
-
-    if (mode in ("decode", "verify") and row_valid is not None
-            and new_cache is not None and not _is_paged(cache)):
-        new_cache = _mask_rows(new_cache, cache, row_valid)
 
     if cfg.post_norms:
         out = norm(out, lp["post_norm1"], cfg.norm_type, cfg.norm_eps)
@@ -350,12 +358,16 @@ def _run_stack(x, params, cfg: ArchConfig, positions, mode: str,
     """Scan the group stack + unrolled tail.  Returns (x, new_cache, aux):
     ``aux`` sums the layers' (:func:`_apply_layer`).
 
-    In the modes that write a cache, the paged KV pool is updated where it
-    lies: its stacked (n_groups, num_pages, page, kv, ·) leaves ride in the
-    scan carry and each layer scatters into and gathers from its own index
-    of them, so no layer slices out its whole slab and writes it back (as
-    ``xs``/``ys`` would).  Per-slot state — rings, recurrent state,
-    contiguous caches — stays in ``xs``/``ys``.
+    In the modes that thread a cache (decode, prefill_chunk, verify), the
+    cache is updated where it lies: every stacked (n_groups, ...) leaf of
+    the group cache — paged pool, SSD state and conv history, RG-LRU
+    state, sliding-window ring — rides in the scan carry, ``xs`` holds
+    only the parameters and the layer index, and each layer reads and
+    writes its own index of its leaves (one slot's of per-slot state in a
+    chunk).  No layer slices out its whole slab to write it back as
+    ``xs``/``ys`` would, and no program copies the cache at entry or exit.
+    ``prefill`` returns the layers' caches as ``ys``; the unrolled tail
+    takes and returns its layers' own caches.
     """
     n_groups, n_tail = _group_layout(cfg)
     kinds = cfg.layer_kinds
@@ -365,46 +377,36 @@ def _run_stack(x, params, cfg: ArchConfig, positions, mode: str,
     threads_cache = mode in ("decode", "prefill_chunk", "verify")
 
     if n_groups:
-        has_cache = mode in cached_modes
-        pools = (tuple(c if _is_paged(c) else None for c in cache["groups"])
-                 if threads_cache else (None,) * cfg.period)
-
         def group_body(carry, xs):
             from repro.distributed.sharding import constrain
-            xc, auxc, pools_c = carry
+            xc, auxc, gc = carry
             # Pin the scan carry (and its saved-for-backward residuals) to
             # batch sharding — inference can drift to weight-style sharding.
             xc = constrain(xc, ("pod", "data"), None, None)
-            gp, gc, i = xs if threads_cache else (xs, None, None)
-            caches_out, pools_out = [], []
+            gp, i = xs if threads_cache else (xs, None)
+            caches_out = []
             for j in range(cfg.period):
-                pool = pools_c[j]
-                layer_cache = (pool if pool is not None
-                               else gc[j] if gc is not None else None)
                 xc, c_new, aux = _apply_layer(
                     xc, _index_tree(gp, j), cfg, kinds[j], positions, mode,
-                    cache=layer_cache, pos=pos, cache_len=cache_len,
-                    page_table=page_table, slot=slot, chunk_pos0=chunk_pos0,
-                    row_valid=row_valid, layer=None if pool is None else i)
-                caches_out.append(c_new if pool is None else None)
-                pools_out.append(c_new if pool is not None else None)
+                    cache=None if gc is None else gc[j], pos=pos,
+                    cache_len=cache_len, page_table=page_table, slot=slot,
+                    chunk_pos0=chunk_pos0, row_valid=row_valid, layer=i)
+                caches_out.append(c_new)
                 auxc = auxc + aux
-            ys = tuple(caches_out) if has_cache else None
-            return (xc, auxc, tuple(pools_out)), ys
+            if threads_cache:
+                return (xc, auxc, tuple(caches_out)), None
+            ys = tuple(caches_out) if mode in cached_modes else None
+            return (xc, auxc, None), ys
 
         body = _remat(group_body, cfg)
         if threads_cache:
-            rest = tuple(None if pool is not None else c
-                         for pool, c in zip(pools, cache["groups"]))
-            xs = (params["groups"], rest,
-                  jnp.arange(n_groups, dtype=jnp.int32))
+            carry = (x, aux_total, cache["groups"])
+            xs = (params["groups"], jnp.arange(n_groups, dtype=jnp.int32))
         else:
-            xs = params["groups"]
-        (x, aux_total, pools), ys = jax.lax.scan(
-            body, (x, aux_total, pools), xs)
+            carry, xs = (x, aux_total, None), params["groups"]
+        (x, aux_total, carried), ys = jax.lax.scan(body, carry, xs)
         if mode in cached_modes:
-            new_cache["groups"] = tuple(
-                pool if pool is not None else y for pool, y in zip(pools, ys))
+            new_cache["groups"] = carried if threads_cache else ys
 
     for j in range(n_tail):
         idx = n_groups * cfg.period + j

@@ -237,7 +237,9 @@ def ssd_decode(x, p, cfg, cache) -> Tuple[jax.Array, dict]:
     a = -jnp.exp(p["A_log"].astype(jnp.float32))
     da = jnp.exp(dt * a)                                          # (B, H)
     xh = x_in.reshape(-1, n_heads, s.head_dim)
-    upd = jnp.einsum("bh,bhp,bn->bhpn", dt, xh, bmat)
+    # Broadcast products, not an einsum: an outer-product dot_general keeps
+    # the TPU compiler from updating a carried state in place.
+    upd = (dt[..., None] * xh)[..., None] * bmat[:, None, None, :]
     state = cache["state"] * da[..., None, None] + upd
     y = jnp.einsum("bhpn,bn->bhp", state, cmat)
     y = y + p["D"].astype(jnp.float32)[:, None] * xh

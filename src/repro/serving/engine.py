@@ -28,8 +28,8 @@ in-flight step delivered, host state exact — at eviction, speculation,
 snapshot, deadline-cancellation, sequence-horizon and fault boundaries,
 so the resilience and rewind invariants below are unchanged.  The cache
 argument of the decode and prefill-chunk programs is donated
-(``donate_argnums``): back-to-back steps update the paged pool in place
-instead of copying it.  Greedy outputs are bit-identical with
+(``donate_argnums``): back-to-back steps update the paged pool and the
+per-slot state in place instead of copying them.  Greedy outputs are bit-identical with
 ``async_steps`` on or off (same program, same inputs — only delivery
 timing differs); ``--no-async`` in ``launch/serve.py`` is the escape
 hatch.

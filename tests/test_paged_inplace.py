@@ -1,11 +1,14 @@
-"""The paged KV pool is updated where it lies.
+"""The serving cache is updated where it lies.
 
-The layer scan carries the stacked pool leaves and each paged layer
-scatters into / gathers from its own index of them; the decode and
-prefill-chunk programs take the pool donated.  These tests hold that
+The layer scan carries every stacked leaf of the group cache: each paged
+layer scatters into / gathers from its own index of the pool, and each
+layer with per-slot state (rings, RG-LRU and Mamba-2 state) reads and
+writes its own index, one slot's in a prefill chunk; the decode and
+prefill-chunk programs take the cache donated.  These tests hold that
 formulation to the one it replaced — every cache leaf in the scan's
 ``xs``/``ys``, one layer slab sliced out and written back per layer —
-bit for bit, and guard the mechanism in the compiled programs.
+bit for bit, hold the per-slot state to its slot discipline, and guard
+the mechanism in the compiled programs.
 """
 import dataclasses
 import re
@@ -44,7 +47,7 @@ def _xs_ys_run_stack(x, params, cfg, positions, mode, cache=None, pos=None,
             auxc = auxc + aux
         return (xc, auxc), tuple(outs)
 
-    (x, aux), ys = jax.lax.scan(body, (x, jnp.zeros((), jnp.float32)),
+    (x, aux), ys = jax.lax.scan(body, (x, model_lib._zero_aux(cfg)),
                                 (params["groups"], cache["groups"]))
     tail = []
     for j in range(n_tail):
@@ -146,6 +149,60 @@ def test_carried_pool_matches_xs_ys_stack(arch, kv_format, mode,
         if mode == "decode":
             # the inactive slot's token lands in null page 0, slot 0
             assert not np.array_equal(old_k[:, 0, 0], new_k[:, 0, 0])
+
+
+@pytest.mark.parametrize("mode", ["decode", "prefill_chunk",
+                                  "prefill_chunk_fresh", "verify"])
+@pytest.mark.parametrize("arch", ["recurrentgemma_9b", "granite4_h_small",
+                                  "mamba2_130m"])
+def test_carried_state_matches_xs_ys_stack(arch, mode, monkeypatch):
+    """Layers with per-slot state — RG-LRU and a sliding-window ring in
+    groups of three plus a two-layer unrolled tail, Mamba-2 around a paged
+    NoPE attention layer with held experts, Mamba-2 alone — give the same
+    logits and cache leaves, bit for bit, with the state in the scan carry
+    as with the state in ``xs``/``ys``, in one decode step, one prefill
+    chunk that resumes a slot or starts it, and one verify window.  A
+    chunk on slot 1 writes that slot's state and leaves every other
+    slot's state and conv history as they were; a decode or verify step
+    writes the valid rows' and leaves the invalid row's."""
+    cfg = get_config(arch).reduced()
+    if arch == "recurrentgemma_9b":
+        cfg = dataclasses.replace(cfg, n_layers=8)
+    assert model_lib._group_layout(cfg)[1] == (2 if arch ==
+                                               "recurrentgemma_9b" else 0)
+    params = model_lib.init_params(jax.random.PRNGKey(0), cfg)
+    cache = _filled_cache(cfg, jax.random.PRNGKey(1))
+    batch = _batch(cfg, mode.removesuffix("_fresh"), jax.random.PRNGKey(2))
+
+    def program():      # a new function each time, so each run traces anew
+        if mode == "prefill_chunk_fresh":
+            return lambda p, b, c: model_lib.prefill_chunk(p, b, c, cfg,
+                                                           pos0=0)
+        return _program(cfg, mode)
+
+    logits, new = jax.jit(program())(params, batch, cache)
+    monkeypatch.setattr(model_lib, "_run_stack", _xs_ys_run_stack)
+    ref_logits, ref = jax.jit(program())(params, batch, cache)
+
+    np.testing.assert_array_equal(np.asarray(logits), np.asarray(ref_logits))
+    assert jax.tree.structure(new) == jax.tree.structure(ref)
+    for a, b in zip(jax.tree.leaves(new), jax.tree.leaves(ref)):
+        assert a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+    # (old, new) of every per-slot state leaf with its slot axis first
+    state = [(o, n) for part, axis in (("groups", 1), ("tail", 0))
+             for oc, nc in zip(cache[part] or (), new[part] or ())
+             if not model_lib._is_paged(oc)
+             for o, n in zip(jax.tree.leaves(oc), jax.tree.leaves(nc))
+             for o, n in [(np.moveaxis(np.asarray(o), axis, 0),
+                           np.moveaxis(np.asarray(n), axis, 0))]]
+    assert state
+    written = [1] if mode.startswith("prefill_chunk") else [0, 1]
+    for old, upd in state:
+        for row in range(SLOTS):
+            same = np.array_equal(old[row], upd[row])
+            assert same == (row not in written), (row, old.shape)
 
 
 # -- the compiled programs ------------------------------------------------

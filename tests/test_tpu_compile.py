@@ -218,14 +218,16 @@ def test_serving_programs_keep_the_pool_in_place(one_chip, program,
     assert not moves, moves
 
 
-@pytest.mark.parametrize("program", ["decode", "chunk"])
-def test_granite_serving_programs_fit_one_chip(one_chip, program):
-    """granite-4.0-h-small's decode and last prefill-chunk programs as its
-    benchmark cell serves them: 20 layers at published widths, 9 of 72
-    experts held, 32 slots of 1280 positions, chunks of 256.  The weights,
-    the pool and the per-slot Mamba-2 state (8.84e9 + 2.79e9 bytes) and
-    the program's temporaries fit one v5e's 16 GiB, the donated cache is
-    aliased to the output, and the expert counts are outputs."""
+GRANITE_SLOTS, GRANITE_CACHE, GRANITE_CHUNK = 32, 1280, 256
+
+
+@pytest.fixture(scope="module")
+def granite_programs(one_chip):
+    """granite-4.0-h-small's serving programs as its benchmark cell serves
+    them, compiled once per module: 20 layers at published widths, 9 of 72
+    experts held, 32 slots of 1280 positions, chunks of 256.  ``decode``
+    is the decode step; ``chunk`` and ``fresh_chunk`` are prefill chunks
+    that resume a slot's state (at 768) and start it (at 0)."""
     import dataclasses
 
     from repro.configs import get_config
@@ -235,7 +237,7 @@ def test_granite_serving_programs_fit_one_chip(one_chip, program):
     base = get_config("granite4_h_small")
     cfg = dataclasses.replace(base, n_layers=20, moe=dataclasses.replace(
         base.moe, held=9))
-    slots, cache_len, chunk = 32, 1280, 256
+    slots, cache_len, chunk = GRANITE_SLOTS, GRANITE_CACHE, GRANITE_CHUNK
     maxp = cache_len // PAGE
 
     def shaped(tree):
@@ -246,33 +248,104 @@ def test_granite_serving_programs_fit_one_chip(one_chip, program):
                                    jax.random.PRNGKey(0)))
     cache = shaped(jax.eval_shape(lambda: model_lib.init_paged_cache(
         cfg, slots, cache_len, num_pages=slots * maxp + 1, page_size=PAGE)))
-    if program == "decode":
-        batch = shaped({"tokens": jnp.zeros((slots, 1), jnp.int32),
-                        "page_table": jnp.zeros((slots, maxp), jnp.int32),
-                        "pos": jnp.zeros(slots, jnp.int32),
-                        "row_valid": jnp.zeros(slots, bool)})
-        extra = shaped((jax.random.PRNGKey(0), jnp.zeros(slots, jnp.float32),
-                        jnp.zeros(slots, bool)))
+    compiled = {}
 
-        def step(p, b, c, key, temps, active):
-            return model_lib.decode_and_sample(
-                p, b, c, cfg, key=key, temperatures=temps,
-                active_rows=active, with_counts=True)
-    else:
-        batch = shaped({"tokens": jnp.zeros((1, chunk), jnp.int32),
-                        "page_table": jnp.zeros((1, maxp), jnp.int32),
-                        "slot": jnp.int32(0)})
-        extra = ()
+    def get(program):
+        if program in compiled:
+            return compiled[program]
+        if program == "decode":
+            batch = shaped({"tokens": jnp.zeros((slots, 1), jnp.int32),
+                            "page_table": jnp.zeros((slots, maxp), jnp.int32),
+                            "pos": jnp.zeros(slots, jnp.int32),
+                            "row_valid": jnp.zeros(slots, bool)})
+            extra = shaped((jax.random.PRNGKey(0),
+                            jnp.zeros(slots, jnp.float32),
+                            jnp.zeros(slots, bool)))
 
-        def step(p, b, c):
-            return model_lib.prefill_chunk(p, b, c, cfg,
-                                           pos0=cache_len - 2 * chunk,
-                                           with_counts=True)
+            def step(p, b, c, key, temps, active):
+                return model_lib.decode_and_sample(
+                    p, b, c, cfg, key=key, temperatures=temps,
+                    active_rows=active, with_counts=True)
+        else:
+            batch = shaped({"tokens": jnp.zeros((1, chunk), jnp.int32),
+                            "page_table": jnp.zeros((1, maxp), jnp.int32),
+                            "slot": jnp.int32(0)})
+            extra = ()
+            pos0 = 0 if program == "fresh_chunk" else cache_len - 2 * chunk
 
-    lowered = jax.jit(step, donate_argnums=(2,)).lower(params, batch, cache,
-                                                       *extra)
+            def step(p, b, c):
+                return model_lib.prefill_chunk(p, b, c, cfg, pos0=pos0,
+                                               with_counts=True)
+
+        lowered = jax.jit(step, donate_argnums=(2,)).lower(
+            params, batch, cache, *extra)
+        exe = lowered.compile()
+        compiled[program] = (lowered, exe.memory_analysis(), exe.as_text())
+        return compiled[program]
+
+    return get
+
+
+@pytest.mark.parametrize("program", ["decode", "chunk"])
+def test_granite_serving_programs_fit_one_chip(granite_programs, program):
+    """granite-4.0-h-small's decode and last prefill-chunk programs as its
+    benchmark cell serves them (:func:`granite_programs`).  The weights,
+    the pool and the per-slot Mamba-2 state (8.84e9 + 2.79e9 bytes) and
+    the program's temporaries fit one v5e's 16 GiB, the donated cache is
+    aliased to the output, and the expert counts are outputs."""
+    lowered, mem, _ = granite_programs(program)
     assert lowered.out_info[-1].shape == (2,)
-    mem = lowered.compile().memory_analysis()
     held = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert held < 0.9 * 2 ** 34, held
     assert mem.alias_size_in_bytes >= 2.79e9
+
+
+def _unfused_lines(text):
+    """The instructions of an HLO module outside fused computations: the
+    ones that yield buffers (a fused computation's values never leave
+    the fusion)."""
+    import re
+    called = set(re.findall(r"(?:calls|to_apply)=%([\w.\-]+)", text))
+    lines, keep = [], False
+    for line in text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) ", line)
+        if head and line.rstrip().endswith("{"):
+            keep = head.group(1) not in called
+        elif keep and line.startswith("  "):
+            lines.append(line)
+    return lines
+
+
+@pytest.mark.parametrize("program", ["decode", "fresh_chunk", "chunk"])
+def test_granite_programs_update_the_state_in_place(granite_programs,
+                                                    program):
+    """The per-slot Mamba-2 state rides in the layer scan's carry: in the
+    decode step and in a prefill chunk that starts a slot (at 0) or
+    resumes one (at 768), no operation yields one layer's state slab
+    (32, 128, 64, 128) of every slot, none copies, transposes or
+    allocates a stacked (2, 32, 128, 64, 128) state leaf (the program
+    reads and writes each where it lies), every stacked state leaf is
+    aliased to its output, and the temporaries stay under 1e9 bytes,
+    where one copy of the state is 2.4e9."""
+    import re
+
+    _, mem, text = granite_programs(program)
+    state = "128,64,128"
+    stacked = f"2,{GRANITE_SLOTS},{state}"
+    slab = re.compile(r"= f32\[(?:1,)?" + f"{GRANITE_SLOTS},{state}" +
+                      r"\]\{")
+    per_layer = [line.strip()[:160] for line in _unfused_lines(text)
+                 if slab.search(line)]
+    assert not per_layer, per_layer
+    moves = re.findall(r"%(\S+) = f32\[" + stacked + r"\]\{[^}]*\} "
+                       r"(?:copy|copy-start|transpose)\(", text)
+    moves += re.findall(r"%(\S+) = f32\[" + stacked + r"\]\{[^}]*\} "
+                        r"custom-call\([^)]*\), custom_call_target="
+                        r"\"AllocateBuffer\"", text)
+    assert not moves, moves
+    entry = text[text.index("\nENTRY"):]
+    leaves = set(re.findall(r"= f32\[" + stacked +
+                            r"\]\{[^}]*\} parameter\((\d+)\)", entry))
+    aliased = set(re.findall(r"\(\s*(\d+),\s*\{\}", text.splitlines()[0]))
+    assert len(leaves) == 9 and leaves <= aliased, (leaves, aliased)
+    assert mem.temp_size_in_bytes < 1.0e9, mem.temp_size_in_bytes
